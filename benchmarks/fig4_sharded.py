@@ -34,9 +34,10 @@ import warnings; warnings.filterwarnings("ignore")
 import jax, numpy as np
 from repro.core.two_level import TwoLevelConfig, build_two_level
 from repro.distributed.backend import ShardedSearchBackend
+from repro.launch.mesh import make_mesh
 
 S = int(sys.argv[1]); n = int(sys.argv[2]); nq = int(sys.argv[3])
-mesh = jax.make_mesh((S,), ("data",))
+mesh = make_mesh((S,), ("data",))
 rng = np.random.default_rng(0)
 c = rng.normal(size=(32, 32)) * 4
 db = (c[rng.integers(0, 32, n)] + rng.normal(size=(n, 32))).astype(np.float32)

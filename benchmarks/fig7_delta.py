@@ -92,8 +92,9 @@ def run(n: int = 20000, d: int = 32, n_clusters: int = 64,
 
     from repro.core.two_level import TwoLevelConfig, build_two_level
     from repro.distributed.backend import ShardedSearchBackend
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     rng = np.random.default_rng(seed)
     centers = rng.normal(size=(64, d)) * 4
     rows = []

@@ -39,12 +39,11 @@ def _recall(ids, oracle_ids):
 
 
 def run(n: int = 20000, nq: int = 64, k: int = 10) -> None:
-    import jax
-
     from repro.core.lexical import build_lexical_slabs, query_operands
     from repro.core.metadata import FilterSpec, MetadataTable
     from repro.core.two_level import TwoLevelConfig, build_two_level
     from repro.distributed.backend import ShardedSearchBackend
+    from repro.launch.mesh import make_mesh
 
     rng = np.random.default_rng(0)
     db = clustered_corpus(rng, n, 32)
@@ -58,7 +57,7 @@ def run(n: int = 20000, nq: int = 64, k: int = 10) -> None:
     qt, qw = query_operands(
         [list(rng.integers(0, nv, 4)) for _ in range(nq)], slabs)
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     kc = max(16, int(np.sqrt(n)))
     idx_i = build_two_level(db, TwoLevelConfig(
         n_clusters=kc, top="brute", bottom="brute", kmeans_iters=4),

@@ -44,6 +44,9 @@ def main() -> None:
                          "the fast job can persist BENCH_*.json artifacts")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    from repro.launch.serve import use_checkout_compile_cache
+
+    use_checkout_compile_cache()
 
     def want(name):
         return only is None or name in only

@@ -14,8 +14,8 @@ import sys
 
 
 def main(argv=None) -> int:
-    # must be set before any jax import: the TPU plugin probe hangs on
-    # hosts without an accelerator
+    # the gate drives 1-device CPU meshes; select the CPU backend before
+    # any jax import so it does not start on (or look for) a TPU
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     ap = argparse.ArgumentParser(
         prog="python -m repro.analysis",

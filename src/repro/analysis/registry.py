@@ -50,9 +50,9 @@ def register_entry_point(name: str):
 
 
 def _mesh1():
-    import jax
+    from repro.launch.mesh import make_mesh
 
-    return jax.make_mesh((1,), ("data",))
+    return make_mesh((1,), ("data",))
 
 
 def _corpus(rng, n):

@@ -16,12 +16,18 @@ import numpy as np
 
 __all__ = ["l2_topk_exact", "brute_search", "pairwise_l2sq", "batched_l2sq"]
 
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def pairwise_l2sq(q: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
-    """(B, N) squared L2 via the matmul expansion (MXU-friendly)."""
+    """(B, N) squared L2 via the matmul expansion (MXU-friendly).
+
+    Dots run at ``HIGHEST`` precision: the TPU default rounds f32
+    operands to bf16, whose error on large-norm corpora (SIFT's 0..255
+    range) exceeds the gaps between neighbour distances."""
     qn = jnp.sum(q * q, axis=-1, keepdims=True)         # (B, 1)
     xn = jnp.sum(x * x, axis=-1)                        # (N,)
-    return qn + xn[None, :] - 2.0 * (q @ x.T)
+    return qn + xn[None, :] - 2.0 * jnp.matmul(q, x.T, precision=HIGHEST)
 
 
 def batched_l2sq(vecs: jnp.ndarray, q: jnp.ndarray) -> jnp.ndarray:
@@ -32,7 +38,7 @@ def batched_l2sq(vecs: jnp.ndarray, q: jnp.ndarray) -> jnp.ndarray:
     drift between the single-device and sharded paths."""
     return (
         jnp.sum(vecs * vecs, -1)
-        - 2.0 * jnp.einsum("bcd,bd->bc", vecs, q)
+        - 2.0 * jnp.einsum("bcd,bd->bc", vecs, q, precision=HIGHEST)
         + jnp.sum(q * q, -1, keepdims=True)
     )
 

@@ -37,7 +37,9 @@ import numpy as np
 
 __all__ = ["DeltaManifest", "DeltaLog", "merge_manifests"]
 
-_EMPTY = np.zeros(0, dtype=np.int64)
+
+def _empty() -> np.ndarray:
+    return np.zeros(0, dtype=np.int64)
 
 
 def merge_manifests(manifests) -> "DeltaManifest":
@@ -94,8 +96,8 @@ class DeltaManifest:
     version: int
     base_n: int
     n: int
-    dirty_buckets: np.ndarray = _EMPTY
-    tombstones: np.ndarray = _EMPTY
+    dirty_buckets: np.ndarray = dataclasses.field(default_factory=_empty)
+    tombstones: np.ndarray = dataclasses.field(default_factory=_empty)
     lsh_rows_appended: int = 0
     full: bool = False
 

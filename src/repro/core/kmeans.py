@@ -35,6 +35,12 @@ def pad_to_multiple(x: np.ndarray, m: int, axis: int = 0, value=0.0):
     return np.pad(x, widths, constant_values=value), n
 
 
+def _dot_t(x, c):
+    """x @ c.T at full f32 precision (the TPU default rounds to bf16), so
+    assignments on the chip match the CPU build."""
+    return jnp.matmul(x, c.T, precision=jax.lax.Precision.HIGHEST)
+
+
 @partial(jax.jit, static_argnames=("chunk",))
 def _assign_chunked(x: jnp.ndarray, c: jnp.ndarray, chunk: int):
     """argmin_j ||x_i - c_j||^2 via scan over query chunks."""
@@ -42,7 +48,7 @@ def _assign_chunked(x: jnp.ndarray, c: jnp.ndarray, chunk: int):
     c_norm = jnp.sum(c * c, axis=1)                     # (k,)
 
     def step(_, xi):
-        d2 = c_norm[None, :] - 2.0 * (xi @ c.T)         # (chunk, k) + const
+        d2 = c_norm[None, :] - 2.0 * _dot_t(xi, c)      # (chunk, k) + const
         a = jnp.argmin(d2, axis=1).astype(jnp.int32)
         best = jnp.min(d2, axis=1) + jnp.sum(xi * xi, axis=1)
         return None, (a, best)
@@ -58,7 +64,7 @@ def _assign_topm_chunked(x: jnp.ndarray, c: jnp.ndarray, m: int, chunk: int):
     c_norm = jnp.sum(c * c, axis=1)
 
     def step(_, xi):
-        d2 = c_norm[None, :] - 2.0 * (xi @ c.T)
+        d2 = c_norm[None, :] - 2.0 * _dot_t(xi, c)
         neg, ids = jax.lax.top_k(-d2, m)
         return None, (ids.astype(jnp.int32),
                       -neg + jnp.sum(xi * xi, axis=1, keepdims=True))
@@ -68,24 +74,37 @@ def _assign_topm_chunked(x: jnp.ndarray, c: jnp.ndarray, m: int, chunk: int):
     return ids.reshape(n, m), d2.reshape(n, m)
 
 
+# rows per device call of the host helpers: bounds device memory for any
+# corpus (on TPU a (rows, m) int/float result pads m to 128 lanes, so a
+# 10M-row top-4 assignment would need ~10 GB of padding alone)
+BLOCK_ROWS = 1 << 20
+
+
+def _blocked(fn, x: np.ndarray, chunk: int, *args):
+    """Run ``fn(x_block, *args, chunk)`` over blocks of at most
+    ``BLOCK_ROWS`` rows (each padded to a whole number of chunks) and
+    concatenate the per-row results on the host."""
+    x = np.ascontiguousarray(x, dtype=np.float32)
+    chunk = min(chunk, max(1, x.shape[0]))
+    rows = max(chunk, BLOCK_ROWS // chunk * chunk)
+    outs = []
+    for s in range(0, x.shape[0], rows):
+        xb, n = pad_to_multiple(x[s:s + rows], chunk)
+        outs.append([np.asarray(r[:n])
+                     for r in fn(jnp.asarray(xb), *args, chunk)])
+    return tuple(np.concatenate(parts) for parts in zip(*outs))
+
+
 def _assign_topm(x: np.ndarray, centroids: np.ndarray, m: int,
                  chunk: int = 4096):
     """Host helper: m nearest centroids per row (ids, sq-dists)."""
-    x = np.ascontiguousarray(x, dtype=np.float32)
-    xp, n = pad_to_multiple(x, min(chunk, max(1, x.shape[0])))
-    ids, d2 = _assign_topm_chunked(
-        jnp.asarray(xp), jnp.asarray(centroids), m,
-        min(chunk, max(1, x.shape[0]))
-    )
-    return np.asarray(ids[:n]), np.asarray(d2[:n])
+    return _blocked(_assign_topm_chunked, x, chunk, jnp.asarray(centroids),
+                    m)
 
 
 def kmeans_assign(x: np.ndarray, centroids: np.ndarray, chunk: int = 4096):
     """Host helper: nearest-centroid ids for (possibly huge) x."""
-    x = np.ascontiguousarray(x, dtype=np.float32)
-    xp, n = pad_to_multiple(x, chunk)
-    a, d2 = _assign_chunked(jnp.asarray(xp), jnp.asarray(centroids), chunk)
-    return np.asarray(a[:n]), np.asarray(d2[:n])
+    return _blocked(_assign_chunked, x, chunk, jnp.asarray(centroids))
 
 
 @partial(jax.jit, static_argnames=("k", "chunk"))

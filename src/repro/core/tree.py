@@ -916,7 +916,9 @@ def _split_margin(kind: str, arrays: dict, nodes: jnp.ndarray, q: jnp.ndarray):
         coord = jnp.take_along_axis(q, dim, axis=1)      # (B, W)
         return coord - arrays["tau"][nodes]
     pv = arrays["proj"][nodes]                           # (B, W, d)
-    return jnp.einsum("bwd,bd->bw", pv, q) - arrays["tau"][nodes]
+    return (jnp.einsum("bwd,bd->bw", pv, q,
+                       precision=jax.lax.Precision.HIGHEST)
+            - arrays["tau"][nodes])
 
 
 @partial(

@@ -6,8 +6,7 @@
 ``backend``    — pre-placed search callables that plug into
                  ``serve.engine.ServingEngine`` as ``search_fn``.
 
-All collectives route through :mod:`repro.compat` so the code runs on any
-JAX version regardless of where ``shard_map`` lives.
+All collectives are built with ``jax.shard_map``.
 """
 from repro.distributed.backend import ShardedSearchBackend
 from repro.distributed.sharding import (

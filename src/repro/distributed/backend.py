@@ -348,20 +348,20 @@ class ShardedSearchBackend:
             x, NamedSharding(self.mesh, spec))
         if self.kind == "brute":
             emask = filter_spec.mask(self._meta, self._host_valid.shape[0])
-            dev = put(jnp.asarray(self._host_valid & emask), P(self.axes))
+            dev = put(self._host_valid & emask, P(self.axes))
         elif self.kind == "ivf":
             emask = filter_spec.mask(self._meta, max(self._n, 1))
             b = self._host_bids
             live = (b >= 0) & emask[np.minimum(np.maximum(b, 0),
                                                emask.shape[0] - 1)]
-            dev = put(jnp.asarray(np.where(live, b, -1).astype(np.int32)),
+            dev = put(np.where(live, b, -1).astype(np.int32),
                       P(self.axes, None))
         else:  # forest
             emask = filter_spec.mask(self._meta, max(self._n, 1))
             b = self._host_bids
             live = (b >= 0) & emask[np.minimum(np.maximum(b, 0),
                                                emask.shape[0] - 1)]
-            dev = put(jnp.asarray(np.where(live, b, -1).astype(np.int32)),
+            dev = put(np.where(live, b, -1).astype(np.int32),
                       P(self.axes, None, None))
         if len(self._fmask_cache) >= 64:
             self._fmask_cache.clear()
@@ -758,6 +758,17 @@ class ShardedSearchBackend:
                 return -1
         return total
 
+    def compiled_text(self, batch: int) -> str:
+        """Compiled program text of the semantic search at ``batch``
+        queries — where a chip check looks for the Pallas kernel
+        (``tpu_custom_call``) rather than the jnp reference."""
+        with self._lock:
+            args = self._args
+        q = jax.ShapeDtypeStruct(
+            (batch, int(args[0].shape[-1])), jnp.float32,
+            sharding=NamedSharding(self.mesh, _q_spec(self.query_axes)))
+        return self._fn.lower(*args, q).compile().as_text()
+
     def __call__(self, queries, *, filter_spec=None, mode: str = "semantic",
                  alpha: float = 0.5, q_terms=None, q_weights=None):
         """Search.  ``filter_spec`` (a :class:`repro.core.metadata.
@@ -833,7 +844,7 @@ class ShardedSearchBackend:
                     qts = jax.device_put(qt, qspec)
                     qws = jax.device_put(qw, qspec)
                     a_dev = jax.device_put(
-                        jnp.full((1, 1), float(alpha), dtype=jnp.float32),
+                        np.full((1, 1), float(alpha), np.float32),
                         NamedSharding(self.mesh, P(None, None)))
                     d, i = self._fn_hyb(
                         args[0], self._lex_args[0], self._lex_args[1],

@@ -33,9 +33,8 @@ batch over a second mesh axis (corpus over one, queries over the other),
 so both B and N scale; the merge all-gathers only over the corpus axes and
 results come back sharded over the query axes.
 
-All collectives go through :mod:`repro.compat`'s ``shard_map`` so the
-communication pattern is explicit in the lowered HLO and the code runs on
-any JAX version (``jax.shard_map`` vs the 0.4.x experimental home).
+All collectives are built with ``jax.shard_map`` so the communication
+pattern is explicit in the lowered HLO.
 
 Online mutation: every sharder here can re-place a *mutated* index into
 previously recorded array shapes — ``forest_shard_shapes`` +
@@ -53,10 +52,10 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.brute import batched_l2sq, pairwise_l2sq
 from repro.kernels import ops as kernel_ops
 
@@ -185,9 +184,11 @@ def _brute_device_arrays(db, n_dev, rows=None, alive=None):
     """Zero-pad db rows to the shard grid.  Pads (and tombstoned rows, via
     ``alive``) are masked by an explicit per-row *valid* array rather than
     a row count baked into the jitted program, so a mutated corpus can be
-    re-placed without re-jitting as long as the grid fits.  Returns
-    (padded db, valid mask, rows per shard, real rows)."""
-    db = jnp.asarray(db, jnp.float32)
+    re-placed without re-jitting as long as the grid fits.  Returns host
+    (numpy) arrays — they go straight to their sharded placement, never
+    through the default device — as (padded db, valid mask, rows per
+    shard, real rows)."""
+    db = np.asarray(db, np.float32)
     n = db.shape[0]
     if rows is None:
         rows = -(-n // n_dev)
@@ -198,8 +199,7 @@ def _brute_device_arrays(db, n_dev, rows=None, alive=None):
     valid = np.arange(rows * n_dev) < n
     if alive is not None:
         valid[:n] &= np.asarray(alive, bool)
-    return (jnp.pad(db, ((0, rows * n_dev - n), (0, 0))),
-            jnp.asarray(valid), rows, n)
+    return np.pad(db, ((0, rows * n_dev - n), (0, 0))), valid, rows, n
 
 
 def _merge_gathered(gd, gi, k):
@@ -310,17 +310,16 @@ def _brute_int8_device_arrays(db, n_dev, rows=None, alive=None):
     valid = np.arange(rows * n_dev) < n
     if alive is not None:
         valid[:n] &= np.asarray(alive, bool)
-    return (jnp.asarray(codes), jnp.asarray(scales), jnp.asarray(valid),
-            rows, n)
+    return codes, scales, valid, rows, n
 
 
 def _pad_queries(mesh, queries, query_axes):
-    q = jnp.asarray(queries, jnp.float32)
+    q = np.asarray(queries, np.float32)
     B = q.shape[0]
     n_q = _axes_size(mesh, query_axes) if query_axes else 1
     Bp = -(-B // n_q) * n_q
     if Bp > B:
-        q = jnp.pad(q, ((0, Bp - B), (0, 0)))
+        q = np.pad(q, ((0, Bp - B), (0, 0)))
     return q, B
 
 
@@ -330,14 +329,14 @@ def _pad_term_queries(mesh, q_terms, q_weights, query_axes):
     Pad rows get term id -1 (never matches a slab slot) and weight 0, so
     the padded queries score nothing and are trimmed after the merge —
     same contract as :func:`_pad_queries` for dense queries."""
-    qt = jnp.asarray(q_terms, jnp.int32)
-    qw = jnp.asarray(q_weights, jnp.float32)
+    qt = np.asarray(q_terms, np.int32)
+    qw = np.asarray(q_weights, np.float32)
     B = qt.shape[0]
     n_q = _axes_size(mesh, query_axes) if query_axes else 1
     Bp = -(-B // n_q) * n_q
     if Bp > B:
-        qt = jnp.pad(qt, ((0, Bp - B), (0, 0)), constant_values=-1)
-        qw = jnp.pad(qw, ((0, Bp - B), (0, 0)))
+        qt = np.pad(qt, ((0, Bp - B), (0, 0)), constant_values=-1)
+        qw = np.pad(qw, ((0, Bp - B), (0, 0)))
     return qt, qw, B
 
 
@@ -393,7 +392,7 @@ def _lexical_device_arrays(terms, tf_sat, n_dev, rows=None, alive=None):
     valid = np.arange(rows * n_dev) < n
     if alive is not None:
         valid[:n] &= np.asarray(alive, bool)
-    return (jnp.asarray(tp), jnp.asarray(fp), jnp.asarray(valid), rows, n)
+    return tp, fp, valid, rows, n
 
 
 def make_sharded_lexical_fn(mesh, axes: tuple, k: int, shard_rows: int,
@@ -562,7 +561,8 @@ def _ivf_device_arrays(index, n_dev, cap=None):
     (zero vectors, -1 ids — pads are masked by index, never by inf).
     ``cap`` pads the bucket width beyond the index's own (update headroom:
     a mutated index re-places into the same shapes, so the jitted search
-    is reused)."""
+    is reused).  The bucket gather runs on the host: the tensor is several
+    times the corpus (deep-10m: ~10 GB) and goes straight to its shards."""
     K, cap_now = index.bucket_ids.shape
     if cap is None:
         cap = cap_now
@@ -572,13 +572,12 @@ def _ivf_device_arrays(index, n_dev, cap=None):
             f"backend (or raise headroom)")
     Kp = -(-K // n_dev) * n_dev
     pad = Kp - K
-    cents = jnp.pad(jnp.asarray(index.centroids, jnp.float32),
-                    ((0, pad), (0, 0)))
-    bids = jnp.pad(jnp.asarray(index.bucket_ids),
-                   ((0, pad), (0, cap - cap_now)), constant_values=-1)
-    dbj = jnp.asarray(index.db)
-    bvecs = dbj[jnp.maximum(bids, 0)]
-    bvecs = jnp.where((bids >= 0)[..., None], bvecs, 0.0)
+    cents = np.pad(np.asarray(index.centroids, np.float32),
+                   ((0, pad), (0, 0)))
+    bids = np.pad(np.asarray(index.bucket_ids, np.int32),
+                  ((0, pad), (0, cap - cap_now)), constant_values=-1)
+    bvecs = np.asarray(index.db, np.float32)[np.maximum(bids, 0)]
+    bvecs[bids < 0] = 0.0
     return cents, bids, bvecs, Kp
 
 
@@ -1146,7 +1145,7 @@ def _forest_device_arrays(mesh, index, axes, n_dev, shapes=None):
     sh = shard_forest(index, n_dev, shapes=shapes)
     max_depth = sh.pop("max_depth")
     put = lambda x: jax.device_put(
-        jnp.asarray(x),
+        np.asarray(x),
         NamedSharding(mesh, P(tuple(axes), *([None] * (np.ndim(x) - 1)))),
     )
     return {name: put(v) for name, v in sh.items()}, max_depth

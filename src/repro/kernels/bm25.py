@@ -12,6 +12,11 @@ the T query term slots (T is small, ~8): each slot broadcasts one term
 id against the (BN, S) slab tile, masks, and contracts over S on the
 VPU.  The semantic term rides the MXU exactly as in ``l2_topk``.
 
+Tiles are derived from the slab width (:func:`lexical_tiles`): each term
+slot's ``(bq, bn, S)`` match temporaries must stay within
+``LEX_TEMP_BYTES`` of VMEM (S is padded to 128 lanes), so the query tile
+is 8 rows and the doc tile the largest 128-multiple that fits.
+
 ``alpha`` is a **(1, 1) operand, not a static argument** — sweeping the
 semantic/lexical blend must not mint new executables (the recompile
 gate covers the hybrid entry).  Grid, liveness (``valid``), clamp, and
@@ -25,10 +30,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import INF, merge_topk, pad_sentinel, valid_operand
+from repro.kernels.common import (
+    HIGHEST, INF, merge_topk, pad_sentinel, valid_operand,
+)
 
-DEFAULT_BQ = 64
-DEFAULT_BN = 256
+LEX_BQ = 8
+LEX_TEMP_BYTES = 1 << 20
+
+
+def lexical_tiles(S: int):
+    """(bq, bn) for a slab of width S: ``bq * bn * roundup(S, 128) * 4``
+    stays within ``LEX_TEMP_BYTES``; bn is a 128-multiple (the ``(1, bn)``
+    liveness block's lane rule)."""
+    lane = -(-S // 128) * 128 * 4
+    return LEX_BQ, max(128, LEX_TEMP_BYTES // (LEX_BQ * lane) // 128 * 128)
 
 
 def _lexical_tile(qt, qw, terms, tf_sat):
@@ -88,7 +103,7 @@ def _kernel_hybrid(q_ref, x_ref, qt_ref, qw_ref, t_ref, f_ref, a_ref,
     xn = jnp.sum(x * x, axis=1)
     dots = jax.lax.dot_general(
         q, x, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=HIGHEST,
     )
     d2 = qn + xn[None, :] - 2.0 * dots            # (BQ, BN)
     score = _lexical_tile(qt_ref[...], qw_ref[...].astype(jnp.float32),
@@ -101,9 +116,10 @@ def _kernel_hybrid(q_ref, x_ref, qt_ref, qw_ref, t_ref, f_ref, a_ref,
     bi_ref[...] = new_i
 
 
-def _grid(bsz, n, bq, bn):
-    bq = min(bq, max(8, bsz))
-    bn = min(bn, max(8, n))
+def _grid(bsz, n, S, bq, bn):
+    auto_bq, auto_bn = lexical_tiles(S)
+    bq = min(bq or auto_bq, max(8, bsz))
+    bn = min(bn or auto_bn, max(8, n))
     return bq, bn, -(-bsz // bq), -(-n // bn)
 
 
@@ -116,15 +132,15 @@ def bm25_topk_pallas(
     k: int = 10,
     *,
     valid: jnp.ndarray | None = None,
-    bq: int = DEFAULT_BQ,
-    bn: int = DEFAULT_BN,
+    bq: int | None = None,
+    bn: int | None = None,
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (ranking dists = -bm25 (B, k) ascending, ids (B, k))."""
     B, T = q_terms.shape
     N, S = terms.shape
     k_eff = min(k, N)
-    bq, bn, grid_b, grid_n = _grid(B, N, bq, bn)
+    bq, bn, grid_b, grid_n = _grid(B, N, S, bq, bn)
     qtp = jnp.pad(q_terms, ((0, grid_b * bq - B), (0, 0)),
                   constant_values=-1)
     qwp = jnp.pad(q_weights, ((0, grid_b * bq - B), (0, 0)))
@@ -168,8 +184,8 @@ def hybrid_topk_pallas(
     k: int = 10,
     *,
     valid: jnp.ndarray | None = None,
-    bq: int = DEFAULT_BQ,
-    bn: int = DEFAULT_BN,
+    bq: int | None = None,
+    bn: int | None = None,
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Fused ``alpha * l2sq - (1 - alpha) * bm25`` top-k."""
@@ -178,7 +194,7 @@ def hybrid_topk_pallas(
     T = q_terms.shape[1]
     S = terms.shape[1]
     k_eff = min(k, N)
-    bq, bn, grid_b, grid_n = _grid(B, N, bq, bn)
+    bq, bn, grid_b, grid_n = _grid(B, N, S, bq, bn)
     qp = jnp.pad(queries, ((0, grid_b * bq - B), (0, 0)))
     xp = jnp.pad(db, ((0, grid_n * bn - N), (0, 0)))
     qtp = jnp.pad(q_terms, ((0, grid_b * bq - B), (0, 0)),

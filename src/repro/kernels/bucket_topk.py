@@ -13,6 +13,15 @@ output block.  The kernel optionally *continues* a running best list
 IVF ``lax.scan`` over probe steps chains one kernel launch per probe
 without re-ranking from scratch.
 
+Tiles are derived from the shapes, not fixed: one ``(bq, bc, d)``
+candidate block must fit ``VMEM_BLOCK_BYTES`` (it is double-buffered and
+the kernel keeps a same-sized temporary, so three of them share the
+chip's scoped VMEM).  ``bc`` is the whole candidate width when a
+minimum-height block fits, else the fewest 128-multiple chunks that do
+(the ``(8, 128)`` block rule: the ``(bq, bc)`` id block's last dim is a
+multiple of 128 or the full width); ``bq`` is then the most 8-row query
+groups that fit, at most 64.
+
 Ids are caller-supplied (bucket slot ids / global entity ids), already
 arbitrary-order; ``id < 0`` marks a dead candidate (empty bucket slot or
 grid pad) and scores +inf.  Ties break on the (distance, id) pair (see
@@ -29,10 +38,26 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import INF, merge_topk
+from repro.kernels.common import HIGHEST, INF, merge_topk
 
-DEFAULT_BQ = 64
-DEFAULT_BC = 256
+MAX_BQ = 64
+VMEM_BLOCK_BYTES = 4 << 20
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def candidate_tiles(B: int, C: int, D: int, itemsize: int = 4):
+    """(bq, bc) for a (B, C, D) candidate tensor: the largest tiles whose
+    ``(bq, bc, D)`` block stays within ``VMEM_BLOCK_BYTES``, where VMEM
+    pads the lane dim D to 128 and the sublane dim bc to 8."""
+    lane = _round_up(D, 128) * itemsize
+    n_c = -(-(_round_up(C, 8) * 8 * lane) // VMEM_BLOCK_BYTES)
+    bc = C if n_c <= 1 else _round_up(-(-C // n_c), 128)
+    fit = VMEM_BLOCK_BYTES // (_round_up(bc, 8) * lane) // 8 * 8
+    bq = max(8, min(MAX_BQ, fit, _round_up(B, 8)))
+    return bq, bc
 
 
 def _kernel(q_ref, v_ref, i_ref, b0d_ref, b0i_ref, bd_ref, bi_ref,
@@ -53,7 +78,7 @@ def _kernel(q_ref, v_ref, i_ref, b0d_ref, b0i_ref, bd_ref, bi_ref,
     qn = jnp.sum(q * q, axis=-1, keepdims=True)   # (BQ, 1)
     dots = jax.lax.dot_general(
         vecs, q, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=HIGHEST,
     )                                             # (BQ, BC)
     d2 = vn - 2.0 * dots + qn
     d2 = jnp.where(ids >= 0, d2, INF)
@@ -74,8 +99,8 @@ def candidate_topk_pallas(
     *,
     best_d: jnp.ndarray | None = None,   # (B, k) carried running best
     best_i: jnp.ndarray | None = None,
-    bq: int = DEFAULT_BQ,
-    bc: int = DEFAULT_BC,
+    bq: int | None = None,
+    bc: int | None = None,
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (dists (B, k) ascending fp32, ids (B, k) int32).
@@ -83,11 +108,13 @@ def candidate_topk_pallas(
     When ``best_d``/``best_i`` are given the result is the merge of the
     carried list with the candidate tile (the IVF probe-chain pattern);
     otherwise the list starts from the ``(inf, -1)`` sentinel.  ``k``
-    may exceed C — unfilled slots return the sentinel.
+    may exceed C — unfilled slots return the sentinel.  ``bq``/``bc``
+    override the derived tiles (:func:`candidate_tiles`).
     """
     B, C, D = vecs.shape
-    bq = min(bq, max(8, B))
-    bc = min(bc, max(8, C))
+    auto_bq, auto_bc = candidate_tiles(B, C, D, vecs.dtype.itemsize)
+    bq = min(bq or auto_bq, max(8, B))
+    bc = min(bc or auto_bc, max(8, C))
     grid_b = -(-B // bq)
     grid_c = -(-C // bc)
     qp = jnp.pad(queries.astype(jnp.float32), ((0, grid_b * bq - B), (0, 0)))

@@ -11,6 +11,9 @@ import jax
 import jax.numpy as jnp
 
 INF = float("inf")  # python float: jnp closures may not capture arrays
+# f32 dots at full precision: Mosaic's and XLA's TPU default rounds the
+# operands to bf16, too coarse for exact-recall L2 on large-norm corpora
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def merge_topk(best_d, best_i, tile_d, tile_i, k: int):
@@ -27,22 +30,38 @@ def merge_topk(best_d, best_i, tile_d, tile_i, k: int):
     running list in an earlier tile — an order that depends on the ``bn``
     tiling — so the lexicographic rule is what makes fused-vs-reference
     conformance bitwise rather than merely set-equal.
+
+    The K rounds run as a loop over the concatenated scores carried in
+    the loop state, so every round's min and tie test read the same
+    stored values.  Unrolled, XLA is free to re-evaluate the (fused)
+    distance arithmetic separately for the min and for the ``==`` test;
+    the two evaluations can differ in the last bit, no entry then ties
+    the minimum, and the id ``int32 max`` would be emitted.  NaN scores
+    rank as +inf for the same reason: NaN ties nothing.
     Returns updated (best_d (B,K) ascending, best_i (B,K)).
     """
     cat_d = jnp.concatenate([best_d, tile_d], axis=1)          # (B, K+T)
     cat_i = jnp.concatenate([best_i, tile_i], axis=1)
+    cat_d = jnp.where(jnp.isnan(cat_d), INF, cat_d)
     imax = jnp.iinfo(jnp.int32).max
-    out_d, out_i = [], []
-    for _ in range(k):
-        md = jnp.min(cat_d, axis=1)                            # (B,)
-        tie = cat_d == md[:, None]
-        mi = jnp.min(jnp.where(tie, cat_i, imax), axis=1)
-        out_d.append(md)
-        out_i.append(mi)
+    col = jax.lax.broadcasted_iota(jnp.int32, (cat_d.shape[0], k), 1)
+
+    def round_(r, carry):
+        cat_d, out_d, out_i = carry
+        md = jnp.min(cat_d, axis=1, keepdims=True)             # (B, 1)
+        tie = cat_d == md
+        mi = jnp.min(jnp.where(tie, cat_i, imax), axis=1, keepdims=True)
+        out_d = jnp.where(col == r, md, out_d)
+        out_i = jnp.where(col == r, mi, out_i)
         # retire exactly the selected (distance, id) entry; duplicate
         # (INF, -1) sentinels re-selecting is harmless and intended
-        cat_d = jnp.where(tie & (cat_i == mi[:, None]), INF, cat_d)
-    return jnp.stack(out_d, axis=1), jnp.stack(out_i, axis=1)
+        cat_d = jnp.where(tie & (cat_i == mi), INF, cat_d)
+        return cat_d, out_d, out_i
+
+    init = (cat_d, jnp.full(col.shape, INF, jnp.float32),
+            jnp.full(col.shape, -1, jnp.int32))
+    _, out_d, out_i = jax.lax.fori_loop(0, k, round_, init)
+    return out_d, out_i
 
 
 def valid_operand(valid, n: int, n_pad: int) -> jnp.ndarray:

@@ -32,7 +32,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import INF, merge_topk, pad_sentinel, valid_operand
+from repro.kernels.common import (
+    HIGHEST, INF, merge_topk, pad_sentinel, valid_operand,
+)
 
 DEFAULT_BQ = 256
 DEFAULT_BN = 512
@@ -62,7 +64,7 @@ def _kernel(q_ref, x_ref, v_ref, bd_ref, bi_ref, *, k: int, bn: int, n: int):
     # MXU: (BQ, D) @ (D, BN)
     dots = jax.lax.dot_general(
         q, x, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=HIGHEST,
     )
     d2 = qn + xn[None, :] - 2.0 * dots            # (BQ, BN)
     d2, ids = _mask_tile(d2, v_ref, step, bn, n)
@@ -93,7 +95,7 @@ def _kernel_int8(q_ref, x_ref, s_ref, v_ref, bd_ref, bi_ref,
     xn8 = jnp.sum(xf * xf, axis=1)                # (BN,) code-space norms
     dots = jax.lax.dot_general(
         q, xf, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=HIGHEST,
     )                                             # (BQ, BN) code-space
     d2 = qn + (s * s * xn8)[None, :] - 2.0 * s[None, :] * dots
     d2, ids = _mask_tile(d2, v_ref, step, bn, n)

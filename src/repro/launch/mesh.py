@@ -1,14 +1,20 @@
-"""Production meshes.
+"""Meshes: the one constructor every library, test and benchmark site uses.
 
 Functions, not module-level constants — importing this module never touches
 jax device state.  The dry-run (and only the dry-run) forces 512 host
 devices; tests and benches see the real single CPU device.
+
+Every mesh is built by :func:`make_mesh` with ``Auto`` axis types.  JAX's
+own ``jax.make_mesh`` defaults to ``Explicit`` axes, on which the sharded
+backend's in-place delta scatters and ``ShardPlan.constrain`` are refused;
+building all meshes here keeps library and test meshes the same kind of
+object.
 """
 from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.distributed.sharding import (
     MULTI_POD_PLAN,
@@ -16,24 +22,30 @@ from repro.distributed.sharding import (
     ShardPlan,
 )
 
-__all__ = ["make_production_mesh", "make_plan", "make_test_mesh",
+__all__ = ["make_mesh", "make_production_mesh", "make_plan",
            "make_cell_meshes"]
+
+
+def make_mesh(shape, axes, *, devices=None) -> Mesh:
+    """``Auto``-axis mesh of ``shape`` over the first devices of
+    ``devices`` (default: ``jax.devices()``)."""
+    shape = tuple(shape)
+    n = int(np.prod(shape))
+    devs = list(jax.devices()) if devices is None else list(devices)
+    if len(devs) < n:
+        raise RuntimeError(
+            f"a {shape} mesh needs {n} devices, found {len(devs)} — force "
+            "host devices via XLA_FLAGS=--xla_force_host_platform_device_"
+            "count before importing jax")
+    return Mesh(np.asarray(devs[:n]).reshape(shape), tuple(axes),
+                axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """16x16 single pod (256 chips) or 2x16x16 two pods (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = int(np.prod(shape))
-    devs = jax.devices()
-    if len(devs) < n:
-        raise RuntimeError(
-            f"need {n} devices, found {len(devs)} — set "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
-            "importing jax (dryrun.py does this)"
-        )
-    dev_array = np.asarray(devs[:n]).reshape(shape)
-    return Mesh(dev_array, axes)
+    return make_mesh(shape, axes)
 
 
 def make_plan(mesh: Mesh) -> ShardPlan:
@@ -41,13 +53,6 @@ def make_plan(mesh: Mesh) -> ShardPlan:
     if "pod" in mesh.axis_names:
         return MULTI_POD_PLAN.with_mesh(mesh)
     return SINGLE_POD_PLAN.with_mesh(mesh)
-
-
-def make_test_mesh(shape=(2, 4), axes=("data", "model")) -> Mesh:
-    """Small mesh over however many fake devices a test forced."""
-    n = int(np.prod(shape))
-    dev_array = np.asarray(jax.devices()[:n]).reshape(shape)
-    return Mesh(dev_array, axes)
 
 
 def make_cell_meshes(n_cells: int, *, shape=None, axes=None, devices=None,
@@ -103,5 +108,5 @@ def make_cell_meshes(n_cells: int, *, shape=None, axes=None, devices=None,
                      for j in range(n_per)]
         else:
             block = devs[i * n_per:(i + 1) * n_per]
-        meshes.append(Mesh(np.asarray(block).reshape(shape), tuple(axes)))
+        meshes.append(make_mesh(shape, axes, devices=block))
     return meshes

@@ -10,8 +10,7 @@ Two integration points:
     (models the end-to-end numerics anywhere, used by default when
     ``compress_grads`` is on; convergence-parity tested).
   * ``compressed_psum`` — an explicit shard_map collective (build the
-    wrapper with :func:`repro.compat.shard_map`, which papers over the
-    ``jax.shard_map`` vs ``jax.experimental.shard_map`` move) that
+    wrapper with ``jax.shard_map``) that
     all-gathers int8 payloads and reduces locally: 4x less cross-pod
     traffic than an fp32 all-reduce.  Used by the hand-rolled DP driver
     and exercised on the fake 8-device mesh in tests.
@@ -78,8 +77,7 @@ def make_ef_transform():
 def compressed_psum(x, axis_name):
     """int8 all-gather + local reduce — a compressed mean over ``axis``.
 
-    Must run inside shard_map (``repro.compat.shard_map`` for the
-    version-portable entry).  Payload: 1 byte/element + one fp32 scale
+    Must run inside ``jax.shard_map``.  Payload: 1 byte/element + one fp32 scale
     per shard, vs 4 bytes/element for fp32 psum.
     """
     q, scale = quantize_int8(x)

@@ -22,6 +22,7 @@ import pytest
 from proptest import run_cases
 from repro.core.brute import brute_search
 from repro.core.metrics import recall_at_k
+from repro.launch.mesh import make_mesh
 from repro.core.two_level import (
     BOTTOM_ALGOS,
     TOP_ALGOS,
@@ -171,7 +172,7 @@ def test_conformance_delta_parity(top, bottom):
     db = _corpus(rng, N)
     p = rng.dirichlet(np.full(N, 0.5)) if bottom == "qlbt" else None
     idx = _build(db, top, bottom, p)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     kw = dict(k=TOPK, axes=("data",), nprobe_local=K, beam_width=8,
               headroom=1.5)
     be_delta = ShardedSearchBackend(mesh, idx, **kw)
@@ -230,7 +231,7 @@ def test_conformance_fused_vs_unfused(top, bottom):
     db = _corpus(rng, N)
     p = rng.dirichlet(np.full(N, 0.5)) if bottom == "qlbt" else None
     idx = _build(db, top, bottom, p)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     kw = dict(k=TOPK, axes=("data",), nprobe_local=K, beam_width=8,
               headroom=1.5)
     be_f = ShardedSearchBackend(mesh, idx, fused=True, **kw)
@@ -274,7 +275,7 @@ def test_conformance_int8_brute_recall():
 
     rng = np.random.default_rng(600)
     db = _corpus(rng, N)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     kw = dict(k=TOPK, axes=("data",), headroom=1.5)
     be32 = ShardedSearchBackend(mesh, db, precision="f32", **kw)
     be8 = ShardedSearchBackend(mesh, db, precision="int8", **kw)

@@ -4,7 +4,7 @@ corpus 2-axis sharding, the serving backend, compressed psum, elastic
 checkpoint resharding, and a sharded LM train step.
 
 The subprocess tests are marked ``slow`` (each pays a fresh 8-device JAX
-start-up); the in-process compat/slicing tests run in the default CI job.
+start-up); the in-process slicing tests run in the default CI job.
 """
 import subprocess
 import sys
@@ -23,6 +23,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import warnings; warnings.filterwarnings("ignore")
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_mesh
 """
 
 
@@ -37,38 +38,17 @@ def _run(body: str):
 
 
 # ---------------------------------------------------------------------------
-# fast, in-process: the compat shim and the forest slicer
+# fast, in-process: the forest slicer
 # ---------------------------------------------------------------------------
-
-
-def test_compat_shard_map_single_device():
-    """The shim resolves a working shard_map and rewrites check_vma /
-    check_rep to whatever the installed JAX accepts."""
-    import jax
-    from jax.sharding import PartitionSpec as P
-
-    from repro.compat import SHARD_MAP_CHECK_KWARG, shard_map
-
-    assert SHARD_MAP_CHECK_KWARG in ("check_vma", "check_rep", None)
-    mesh = jax.make_mesh((1,), ("data",))
-    x = np.arange(4, dtype=np.float32)
-    for kw in ({"check_vma": False}, {"check_rep": False}, {}):
-        fn = shard_map(lambda s: s * 2, mesh=mesh, in_specs=(P("data"),),
-                       out_specs=P("data"), **kw)
-        assert np.allclose(np.asarray(fn(x)), x * 2)
-    with pytest.raises(ValueError):
-        shard_map(lambda s: s, mesh=mesh, in_specs=(P("data"),),
-                  out_specs=P("data"), check_vma=True, check_rep=False)
 
 
 def test_query_axes_must_be_disjoint_from_corpus_axes():
     """A shared axis would top-k-merge results of *different* queries —
     refuse loudly instead of returning silently wrong neighbors."""
-    import jax
-
     from repro.distributed import sharded_brute_search
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     db = np.zeros((8, 4), np.float32)
     with pytest.raises(ValueError, match="disjoint"):
         sharded_brute_search(mesh, db, db[:2], 2,
@@ -273,7 +253,7 @@ def test_sharded_brute_matches_exact():
     out = _run("""
     from repro.distributed import sharded_brute_search
     from repro.core.brute import brute_search
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(0)
     db = rng.normal(size=(3000, 16)).astype(np.float32)
     q = rng.normal(size=(32, 16)).astype(np.float32)
@@ -292,7 +272,7 @@ def test_query_and_corpus_2axis_sharded_matches_exact():
     out = _run("""
     from repro.distributed import sharded_brute_search
     from repro.core.brute import brute_search
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(1)
     db = rng.normal(size=(2500, 16)).astype(np.float32)
     q = rng.normal(size=(37, 16)).astype(np.float32)   # 37 % 4 != 0
@@ -312,7 +292,7 @@ def test_sharded_ivf_recall():
     from repro.core.two_level import TwoLevelConfig, build_two_level
     from repro.core.brute import brute_search
     from repro.core.metrics import recall_at_k
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(0)
     c = rng.normal(size=(32, 16)) * 4
     db = (c[rng.integers(0, 32, 4000)] + rng.normal(size=(4000, 16))).astype(np.float32)
@@ -336,7 +316,7 @@ def test_sharded_forest_recall():
     from repro.core.two_level import TwoLevelConfig, build_two_level
     from repro.core.brute import brute_search
     from repro.core.metrics import recall_at_k
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(0)
     c = rng.normal(size=(32, 16)) * 4
     db = (c[rng.integers(0, 32, 4000)] + rng.normal(size=(4000, 16))).astype(np.float32)
@@ -365,7 +345,7 @@ def test_sharded_ivf_full_probe_identical_to_single_device():
     out = _run("""
     from repro.distributed import sharded_ivf_search
     from repro.core.two_level import TwoLevelConfig, build_two_level
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(3)
     c = rng.normal(size=(32, 16)) * 4
     db = (c[rng.integers(0, 32, 2500)] + rng.normal(size=(2500, 16))).astype(np.float32)
@@ -390,7 +370,7 @@ def test_sharded_forest_full_probe_identical_to_single_device():
     out = _run("""
     from repro.distributed import sharded_forest_search
     from repro.core.two_level import TwoLevelConfig, build_two_level
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(4)
     c = rng.normal(size=(32, 16)) * 4
     db = (c[rng.integers(0, 32, 2700)] + rng.normal(size=(2700, 16))).astype(np.float32)
@@ -416,7 +396,7 @@ def test_serving_engine_sharded_survives_mutation_without_rejit():
     out = _run("""
     from repro.serve.engine import ServingEngine
     from repro.core.two_level import TwoLevelConfig, build_two_level
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(5)
     c = rng.normal(size=(32, 16)) * 4
     def mk(n):
@@ -462,7 +442,7 @@ def test_sharded_delta_apply_identical_to_full_8dev():
     out = _run("""
     from repro.core.two_level import TwoLevelConfig, build_two_level
     from repro.distributed.backend import ShardedSearchBackend
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(6)
     c = rng.normal(size=(32, 16)) * 4
     def mk(n):
@@ -509,7 +489,7 @@ def test_serving_engine_sharded_backend():
     out = _run("""
     from repro.serve.engine import ServingEngine
     from repro.core.brute import brute_search
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(2)
     db = rng.normal(size=(2000, 16)).astype(np.float32)
     eng = ServingEngine.sharded(mesh, db, k=5, max_batch=16, max_wait_ms=2.0)
@@ -526,9 +506,9 @@ def test_serving_engine_sharded_backend():
 @slow
 def test_compressed_psum_approximates_mean():
     out = _run("""
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.train.compression import compressed_psum
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     rng = np.random.default_rng(0)
     x = rng.normal(size=(8, 64)).astype(np.float32)
     fn = shard_map(lambda s: compressed_psum(s[0], "data"),
@@ -552,7 +532,7 @@ def test_elastic_reshard_restore_1_to_8_devices():
             "b": jnp.asarray(rng.normal(size=(64,)).astype(np.float32))}
     with tempfile.TemporaryDirectory() as d:
         C.save(d, 1, tree)                      # saved "single-host"
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         shard = {"w": NamedSharding(mesh, P("data", "model")),
                  "b": NamedSharding(mesh, P("model"))}
         out = C.restore(d, 1, tree, shardings=shard)
@@ -589,7 +569,7 @@ def test_lm_train_step_sharded_equals_local():
     s1, aux1 = local_step(s0, batch)
 
     # sharded
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     plan = ShardPlan(dp=("data",), fsdp=("data",), tp=("model",),
                      ep=("data", "model"), mesh=mesh)
     s0b = init_state(T.init(cfg, key), opt)

@@ -34,6 +34,7 @@ from repro.core.two_level import (
     build_two_level,
 )
 from repro.distributed.backend import ShardedSearchBackend
+from repro.launch.mesh import make_mesh
 
 N, D, K, CAP, NQ, TOPK = 600, 8, 16, 96, 16, 10
 COMBOS = [(t, b) for t in TOP_ALGOS for b in BOTTOM_ALGOS]
@@ -89,7 +90,7 @@ def test_filtered_fused_vs_unfused(top, bottom):
     p = rng.dirichlet(np.full(N, 0.5)) if bottom == "qlbt" else None
     meta = _meta_for(rng, N)
     idx = _build(db, top, bottom, p, metadata=meta)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     kw = dict(k=TOPK, axes=("data",), nprobe_local=K, beam_width=8,
               headroom=1.5)
     be_f = ShardedSearchBackend(mesh, idx, fused=True, **kw)
@@ -156,7 +157,7 @@ def test_filtered_brute_exact_oracle(fused):
     rng = np.random.default_rng(800 + int(fused))
     db = _corpus(rng, N)
     meta = _meta_for(rng, N)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     be = ShardedSearchBackend(
         mesh, db, k=TOPK, axes=("data",), headroom=1.5, fused=fused,
         metadata=meta, delta_max_fraction=1.0)
@@ -214,7 +215,7 @@ def test_lexical_and_hybrid_conformance():
     q = _corpus(rng, 6)
     qt, qw = query_operands(
         [list(rng.integers(0, nv, 5)) for _ in range(6)], slabs)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     kw = dict(k=TOPK, axes=("data",), headroom=1.5, metadata=meta,
               lexical=slabs, delta_max_fraction=1.0)
     be_f = ShardedSearchBackend(mesh, db, fused=True, **kw)
@@ -301,7 +302,7 @@ def test_mode_and_filter_validation():
     rng = np.random.default_rng(901)
     db = _corpus(rng, 64)
     meta = MetadataTable({"pct": np.zeros(64, np.int32)})
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     be = ShardedSearchBackend(mesh, db, k=4, axes=("data",),
                               metadata=meta)
     q = _corpus(rng, 2)
@@ -356,7 +357,7 @@ def test_cache_key_isolation_and_invalidation():
     db = _corpus(rng, n)
     meta = MetadataTable(
         {"pct": (rng.permutation(n) % 100).astype(np.int32)})
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     be = ShardedSearchBackend(mesh, db, k=TOPK, axes=("data",),
                               headroom=1.5, metadata=meta,
                               delta_max_fraction=1.0)

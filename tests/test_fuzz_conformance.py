@@ -43,6 +43,7 @@ from repro.core.two_level import (
     build_two_level,
 )
 from repro.distributed.backend import ShardedSearchBackend
+from repro.launch.mesh import make_mesh
 
 N0, D, K, CAP, TOPK = 400, 8, 12, 80, 8
 HEADROOM = 1.6
@@ -56,7 +57,7 @@ _MESH = None
 def _mesh():
     global _MESH
     if _MESH is None:
-        _MESH = jax.make_mesh((1,), ("data",))
+        _MESH = make_mesh((1,), ("data",))
     return _MESH
 
 

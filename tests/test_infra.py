@@ -46,7 +46,8 @@ def test_analyze_hlo_counts_collectives():
     import jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.launch.hlo_analysis import analyze_hlo
-    mesh = jax.make_mesh((8,), ("d",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8,), ("d",))
     def f(x, w):
         return (x @ w).sum()
     with mesh:
@@ -93,9 +94,6 @@ def test_shard_plan_roles_resolve():
 
 
 def test_div_p_drops_indivisible_dims():
-    import numpy as np_
-    from repro.launch.mesh import make_test_mesh
-
     # mesh needs real devices; emulate sizes via a fake plan with mesh=None
     # -> size 1 divides everything, roles keep
     p = ShardPlan(dp=("data",), fsdp=("data",), tp=("model",))

@@ -17,6 +17,7 @@ from repro.core.index import build_index
 from repro.core.metrics import recall_at_k
 from repro.core.protocol import IndexSpec
 from repro.core.two_level import TwoLevelConfig, build_two_level
+from repro.launch.mesh import make_mesh
 
 N, D, K = 1500, 12, 24
 
@@ -349,9 +350,7 @@ def test_delta_manifest_accumulates_and_pops():
 
 
 def _mesh1():
-    import jax
-
-    return jax.make_mesh((1,), ("data",))
+    return make_mesh((1,), ("data",))
 
 
 def test_delta_threshold_boundary_falls_back_to_full():
