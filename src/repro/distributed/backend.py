@@ -128,7 +128,6 @@ class ShardedSearchBackend:
         self.n_dev = _axes_size(mesh, self.axes)
         self.delta_updates = delta_updates
         self.delta_max_fraction = delta_max_fraction
-        self.fused = fused
         self.precision = precision
         self.nprobe_local = nprobe_local
         self.beam_width = beam_width
@@ -807,49 +806,53 @@ class ShardedSearchBackend:
             sig = (mode, tuple(q.shape), str(q.dtype))
             b_disp = int(q.shape[0])
         t0 = time.perf_counter()
-        # kernel: queue + device execution of the jitted shard_map scan.
-        # block_until_ready runs OUTSIDE the lock (same concurrency as
-        # before, where device_get did the blocking) so the span measures
-        # real device time, not async dispatch.
+        # kernel: queue + device execution of the jitted shard_map scan,
+        # in two parts: backend.launch (lock wait, query placement and
+        # the enqueue, up to the jitted call's return) and backend.wait
+        # (block_until_ready).  The wait runs OUTSIDE the lock (same
+        # concurrency as before, where device_get did the blocking) so the
+        # span measures real device time, not async dispatch.
         with tracer.span("kernel", kind=self.kind, b=b_disp):
-            with self._lock, self.mesh:
-                first = sig not in self._seen_sigs
-                if first:
-                    self._seen_sigs.add(sig)
-                qspec = NamedSharding(self.mesh, _q_spec(self.query_axes))
-                args = self._args
-                if filter_spec is not None:
-                    fdev = self._filter_operand(filter_spec)
-                    if self.kind == "brute":
-                        if self.precision == "int8":
-                            args = (args[0], args[1], fdev)
-                        else:
-                            args = (args[0], fdev)
-                    elif self.kind == "ivf":
-                        args = (args[0], fdev, args[2])
-                    else:  # forest: bucket_ids is _FOREST_ARGS[3]
-                        args = args[:3] + (fdev,) + args[4:]
-                if mode == "semantic":
-                    qs = jax.device_put(q, qspec)
-                    d, i = self._fn(*args, qs)
-                elif mode == "lexical":
-                    # args[-1] is the (possibly filtered) valid operand
-                    qts = jax.device_put(qt, qspec)
-                    qws = jax.device_put(qw, qspec)
-                    d, i = self._fn_lex(
-                        self._lex_args[0], self._lex_args[1], args[1],
-                        qts, qws)
-                else:  # hybrid
-                    qs = jax.device_put(q, qspec)
-                    qts = jax.device_put(qt, qspec)
-                    qws = jax.device_put(qw, qspec)
-                    a_dev = jax.device_put(
-                        np.full((1, 1), float(alpha), np.float32),
-                        NamedSharding(self.mesh, P(None, None)))
-                    d, i = self._fn_hyb(
-                        args[0], self._lex_args[0], self._lex_args[1],
-                        args[1], qs, qts, qws, a_dev)
-            jax.block_until_ready((d, i))
+            with tracer.span("backend.launch"):
+                with self._lock, self.mesh:
+                    first = sig not in self._seen_sigs
+                    if first:
+                        self._seen_sigs.add(sig)
+                    qspec = NamedSharding(self.mesh, _q_spec(self.query_axes))
+                    args = self._args
+                    if filter_spec is not None:
+                        fdev = self._filter_operand(filter_spec)
+                        if self.kind == "brute":
+                            if self.precision == "int8":
+                                args = (args[0], args[1], fdev)
+                            else:
+                                args = (args[0], fdev)
+                        elif self.kind == "ivf":
+                            args = (args[0], fdev, args[2])
+                        else:  # forest: bucket_ids is _FOREST_ARGS[3]
+                            args = args[:3] + (fdev,) + args[4:]
+                    if mode == "semantic":
+                        qs = jax.device_put(q, qspec)
+                        d, i = self._fn(*args, qs)
+                    elif mode == "lexical":
+                        # args[-1] is the (possibly filtered) valid operand
+                        qts = jax.device_put(qt, qspec)
+                        qws = jax.device_put(qw, qspec)
+                        d, i = self._fn_lex(
+                            self._lex_args[0], self._lex_args[1], args[1],
+                            qts, qws)
+                    else:  # hybrid
+                        qs = jax.device_put(q, qspec)
+                        qts = jax.device_put(qt, qspec)
+                        qws = jax.device_put(qw, qspec)
+                        a_dev = jax.device_put(
+                            np.full((1, 1), float(alpha), np.float32),
+                            NamedSharding(self.mesh, P(None, None)))
+                        d, i = self._fn_hyb(
+                            args[0], self._lex_args[0], self._lex_args[1],
+                            args[1], qs, qts, qws, a_dev)
+            with tracer.span("backend.wait"):
+                jax.block_until_ready((d, i))
         t1 = time.perf_counter()
         # rerank: pull the per-shard top-k merge result back to host and
         # trim query padding — the host half of candidate re-scoring
@@ -869,47 +872,3 @@ class ShardedSearchBackend:
                            shape=str(list(sig[0])), dtype=sig[1],
                            ms=round((t1 - t0) * 1e3, 3))
         return out
-
-    def roofline_report(self, b: int = 1, *, peak_bw: float = 0.0) -> dict:
-        """Analytic bytes/FLOPs for one dispatch next to the *measured*
-        kernel time from live telemetry.
-
-        ``analytic_frac`` is the useful-byte fraction of the cost model
-        (what fraction of moved bytes are corpus bytes a perfect kernel
-        must move); ``achieved_gbps`` divides the model's moved bytes by
-        the median measured kernel time; with ``peak_bw`` (bytes/s, e.g.
-        ``benchmarks.roofline.HBM_BW``) the measured useful-byte fraction
-        ``measured_frac`` = useful bytes/s over peak is reported too.
-        """
-        from repro.obs.profile import backend_cost
-
-        if self.kind == "brute":
-            d = int(np.asarray(self._args[0]).shape[1])
-            cost = backend_cost("brute", fused=self.fused,
-                                precision=self.precision, n_rows=self._n,
-                                d=d, b=b, k=self.k)
-        elif self.kind == "ivf":
-            d = int(np.asarray(self._args[0]).shape[1])
-            cost = backend_cost(
-                "ivf", fused=self.fused, precision=self.precision,
-                n_rows=self._n, d=d, b=b, k=self.k,
-                n_probe_rows=self.nprobe_local * self.n_dev * self._cap,
-                n_centroids=self._Kp)
-        else:
-            d = int(np.asarray(self._args[0]).shape[2])
-            nb = int(np.asarray(self._args[0]).shape[1])
-            cost = backend_cost(
-                "forest", fused=self.fused, precision=self.precision,
-                n_rows=self._n, d=d, b=b, k=self.k,
-                n_probe_rows=(self.nprobe_local * self.n_dev
-                              * self._shapes.cap),
-                n_centroids=self.n_dev * nb)
-        med_ms = self._h_kernel.quantile(0.5) if self._h_kernel.count else 0.0
-        cost["measured_kernel_ms_p50"] = med_ms
-        if med_ms > 0:
-            bps = cost["bytes_moved"] / (med_ms / 1e3)
-            cost["achieved_gbps"] = bps / 1e9
-            if peak_bw > 0:
-                cost["measured_frac"] = (
-                    cost["useful_bytes"] / (med_ms / 1e3)) / peak_bw
-        return cost

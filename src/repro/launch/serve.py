@@ -111,8 +111,15 @@ def serve(meshes, dep: Deployment, *, k: int = 10, precision: str = "f32",
     """Place ``dep`` on every mesh and put a router over the cells.
 
     The config's nprobe is the *global* probe count: each of a cell's
-    chips probes its own best ``ceil(nprobe / chips)`` buckets."""
+    chips probes its own best ``ceil(nprobe / chips)`` buckets.  Installs
+    the process's compile and garbage-collector hooks (``repro.obs``),
+    so a compile or a full collection while serving shows as a span and
+    in ``PROFILE``."""
+    from repro.obs import install_gc_hooks, install_jax_compile_hooks
     from repro.serve.fleet import build_fleet
+
+    install_jax_compile_hooks()
+    install_gc_hooks()
 
     backend_kw = {"precision": precision}
     if dep.kind != "brute":

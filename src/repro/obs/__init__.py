@@ -1,22 +1,22 @@
 """End-to-end observability: metrics registry, request tracing, and
-kernel/compile profiling.
+compile/collector profiling.
 
 Three submodules, one per tentpole concern:
 
 * :mod:`repro.obs.metrics` — fixed-footprint counters/gauges/log-scale
   histograms with JSON snapshot + Prometheus text exposition;
-* :mod:`repro.obs.trace` — span API with propagated trace ids and
+* :mod:`repro.obs.trace` — span API with propagated trace ids, a clock
+  anchor that puts spans on the wall clock of a device trace, and
   Chrome-trace/Perfetto export;
-* :mod:`repro.obs.profile` — compile-event accounting, per-entry-point
-  replay profiling, and the analytic bytes/FLOPs cost model.
+* :mod:`repro.obs.profile` — compile-event and garbage-collector
+  accounting.
 
 See ``docs/observability.md`` for the metric catalog and span taxonomy.
 """
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                merge_snapshots, parse_exposition)
-from repro.obs.profile import (PROFILE, backend_cost,
-                               install_jax_compile_hooks,
-                               profile_entry_points)
+from repro.obs.profile import (PROFILE, install_gc_hooks,
+                               install_jax_compile_hooks)
 from repro.obs.trace import Tracer, get_tracer, set_tracer
 
 __all__ = [
@@ -26,11 +26,10 @@ __all__ = [
     "MetricsRegistry",
     "PROFILE",
     "Tracer",
-    "backend_cost",
     "get_tracer",
+    "install_gc_hooks",
     "install_jax_compile_hooks",
     "merge_snapshots",
     "parse_exposition",
-    "profile_entry_points",
     "set_tracer",
 ]

@@ -182,9 +182,16 @@ class Histogram:
             return self._sum / self._count if self._count else 0.0
 
     def _state(self):
+        # the tuple is built after the lock is released: allocating a
+        # tracked object may start a collection, whose callback
+        # (obs.profile.install_gc_hooks) observes into a histogram
         with self._lock:
-            return (self._counts.copy(), self._count, self._sum,
-                    self._min, self._max)
+            counts = self._counts.copy()
+            count = self._count
+            total = self._sum
+            vmin = self._min
+            vmax = self._max
+        return counts, count, total, vmin, vmax
 
     def quantile(self, q: float) -> float:
         """Approximate quantile: log-interpolated within the covering

@@ -1,5 +1,4 @@
-"""Kernel/compile profiling hooks: compile events, per-dispatch device
-time, and analytic-vs-measured roofline accounting.
+"""Compile and collector profiling hooks.
 
 Three layers, cheapest first:
 
@@ -14,39 +13,36 @@ Three layers, cheapest first:
    production telemetry.
 
 2. **JAX monitoring hooks** (:func:`install_jax_compile_hooks`) — JAX
-   emits ``/jax/core/compile/...`` duration events at every real XLA
-   compile; the listener mirrors them into the process-wide
-   :data:`PROFILE` registry and the active tracer.  Registration is
-   idempotent and survives for the process lifetime (JAX has no
-   unregister), so the listener reads the *current* default tracer at
-   event time.
+   emits ``/jax/core/compile/...`` duration events when it traces,
+   lowers and compiles a function; the listener mirrors each into the
+   process-wide :data:`PROFILE` registry and, as a ``jax-compile`` span
+   of its measured duration named by the function, into the active
+   tracer.
 
-3. **Entry-point accounting** (:func:`profile_entry_points`) — replays
-   the jitted entry points registered in
-   :mod:`repro.analysis.registry` (the same list the recompile gate
-   checks), wall-timing every lifecycle step and attributing
-   compiled-variant growth to the step that triggered it.
+3. **Garbage-collector hooks** (:func:`install_gc_hooks`) — every
+   collection is counted in :data:`PROFILE` by generation
+   (``gc_collections.gen<n>``); a full (generation-2) collection also
+   lands in the ``gc_pause_ms`` histogram and becomes a ``gc`` span on
+   the thread that triggered it.  A full collection holds the
+   interpreter lock for its whole pause, so every thread of the serving
+   path stalls inside it.
 
-The analytic side (:func:`backend_cost`) prices one dispatch of a
-backend in bytes/FLOPs using the same traffic model as
-``benchmarks/roofline.py``'s ``ann_scan_rows`` — so the fused, unfused
-and int8 paths report a *measured* achieved-bandwidth number next to the
-*analytic* useful-byte fraction, per backend, from live telemetry
-(``ShardedSearchBackend.roofline_report``).
+Both listeners are idempotent and stay for the process lifetime (JAX
+offers no unregister), so they read the *current* default tracer at
+event time.
 """
 from __future__ import annotations
 
+import gc
 import time
-from typing import Iterable, Optional
 
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import get_tracer
 
 __all__ = [
     "PROFILE",
-    "backend_cost",
+    "install_gc_hooks",
     "install_jax_compile_hooks",
-    "profile_entry_points",
 ]
 
 # process-wide profiling registry: compile events land here regardless
@@ -54,12 +50,13 @@ __all__ = [
 PROFILE = MetricsRegistry()
 
 _HOOKS_INSTALLED = False
+_GC_HOOK = None
+_COMPILE_PREFIX = "/jax/core/compile/"
 
 
-def install_jax_compile_hooks(metrics: Optional[MetricsRegistry] = None,
-                              ) -> bool:
-    """Mirror JAX's compile-duration monitoring events into ``metrics``
-    (default :data:`PROFILE`) and the current default tracer.
+def install_jax_compile_hooks() -> bool:
+    """Mirror JAX's compile-duration monitoring events into
+    :data:`PROFILE` and the current default tracer.
 
     Returns True when the listener is (already) installed, False when
     this jax build has no monitoring surface.  Idempotent — JAX offers
@@ -67,7 +64,6 @@ def install_jax_compile_hooks(metrics: Optional[MetricsRegistry] = None,
     ever added and it routes through module state.
     """
     global _HOOKS_INSTALLED
-    reg = metrics or PROFILE
     if _HOOKS_INSTALLED:
         return True
     try:
@@ -77,109 +73,65 @@ def install_jax_compile_hooks(metrics: Optional[MetricsRegistry] = None,
     if not hasattr(monitoring, "register_event_duration_secs_listener"):
         return False                          # pragma: no cover
 
+    n_events = PROFILE.counter("jax_compile_events")
+    h_ms = PROFILE.histogram("jax_compile_ms", lo=1e-2, hi=1e6)
+
     def _on_duration(event: str, secs: float, **kw) -> None:
-        if "compile" not in event:
+        # tracing, lowering and the XLA compile; a persistent-cache hit's
+        # "/jax/compilation_cache/compile_time_saved_sec" is no compile
+        if not event.startswith(_COMPILE_PREFIX):
             return
-        reg.counter("jax_compile_events").inc()
-        reg.histogram("jax_compile_ms", lo=1e-2, hi=1e6).observe(
-            secs * 1e3)
-        get_tracer().instant("jax-compile", event=event,
-                             ms=round(secs * 1e3, 3))
+        t1 = time.perf_counter()
+        n_events.inc()
+        h_ms.observe(secs * 1e3)
+        get_tracer().record_span(
+            "jax-compile", t1 - secs, t1,
+            stage=event[len(_COMPILE_PREFIX):],
+            fun=str(kw.get("fun_name", "")))
 
     monitoring.register_event_duration_secs_listener(_on_duration)
     _HOOKS_INSTALLED = True
     return True
 
 
-def backend_cost(kind: str, *, fused: bool, precision: str,
-                 n_rows: int, d: int, b: int, k: int,
-                 n_probe_rows: int = 0, n_centroids: int = 0) -> dict:
-    """Analytic bytes/FLOPs for ONE dispatch of a sharded search.
+def install_gc_hooks() -> bool:
+    """Count every garbage collection in :data:`PROFILE` and record each
+    full one as a ``gc`` span.
 
-    Mirrors ``benchmarks/roofline.py:ann_scan_rows``: the scan is
-    bandwidth-bound, so variants differ almost purely in bytes moved —
-    ``useful_bytes`` is the corpus traffic a perfect kernel must move,
-    ``bytes_moved`` adds the materialized ``(B, N)`` distance matrix
-    (write + read-back) that only the *unfused* brute path pays.
+    Counters ``gc_collections.gen0`` / ``.gen1`` / ``.gen2`` count the
+    collections of each generation; ``gc_pause_ms`` holds the pause of
+    each generation-2 collection.  Only generation 2 gets a span (with
+    ``generation`` and ``collected`` attributes), so the young
+    collections, hundreds a second under load, do not flood the ring.
+    Idempotent: returns True once the callback is in ``gc.callbacks``.
 
-    ``n_rows`` is total corpus rows placed; for ``ivf``/``forest`` the
-    probed subset (``n_probe_rows``) plus the centroid scan
-    (``n_centroids``) is what actually moves per query batch — an
-    estimate (probe sets overlap across a batch), flagged as such in
-    the report.
+    The callback runs inside whatever allocation triggered the
+    collection, on that thread: it touches only instruments resolved
+    here and the tracer, whose lock is re-entrant.
     """
-    if kind == "brute":
-        scanned = n_rows
-        db_bytes = (n_rows * d * 1.0 + n_rows * 4.0
-                    if precision == "int8" else n_rows * d * 4.0)
-    else:
-        scanned = n_probe_rows
-        db_bytes = (n_centroids + n_probe_rows) * d * 4.0
-    out_bytes = b * k * 8.0
-    moved = db_bytes + out_bytes
-    if kind == "brute" and not fused:
-        moved += 2.0 * b * scanned * 4.0     # (B, N) write + read-back
-    flops = 2.0 * b * (n_centroids + scanned) * d
-    return {
-        "kind": kind, "fused": bool(fused), "precision": precision,
-        "useful_bytes": db_bytes, "bytes_moved": moved,
-        "flops": flops,
-        "analytic_frac": db_bytes / moved if moved else 0.0,
-        "estimate": kind != "brute",
-    }
+    global _GC_HOOK
+    if _GC_HOOK is not None:
+        return True
+    counts = [PROFILE.counter(f"gc_collections.gen{g}") for g in range(3)]
+    h_pause = PROFILE.histogram("gc_pause_ms")
+    started = [0.0]
+    # bound here: a collection at interpreter exit may find this
+    # module's globals already cleared
+    clock, tracer = time.perf_counter, get_tracer
 
+    def _on_gc(phase: str, info: dict) -> None:
+        if phase == "start":
+            started[0] = clock()
+            return
+        gen = info["generation"]
+        counts[gen].inc()
+        if gen == 2:
+            t1 = clock()
+            h_pause.observe((t1 - started[0]) * 1e3)
+            tracer().record_span("gc", started[0], t1, generation=gen,
+                                 collected=info["collected"])
 
-def profile_entry_points(names: Optional[Iterable[str]] = None, *,
-                         metrics: Optional[MetricsRegistry] = None,
-                         ) -> dict:
-    """Replay registered jitted entry points, accounting per step.
+    gc.callbacks.append(_on_gc)
+    _GC_HOOK = _on_gc
+    return True
 
-    For each entry point in :mod:`repro.analysis.registry` (or the
-    ``names`` subset): build its Plan, run the steps in order, and
-    record per step the wall time and the compiled-variant growth its
-    mutations triggered.  Returns ``{name: {"steps": [...],
-    "compiles": int, "wall_ms": float}}`` and mirrors the numbers into
-    ``metrics`` (default :data:`PROFILE`) + spans into the tracer —
-    the per-entry-point compile ledger the ISSUE's tuner work reads.
-    """
-    from repro.analysis.registry import ENTRY_POINTS
-
-    install_jax_compile_hooks(metrics)
-    reg = metrics or PROFILE
-    tracer = get_tracer()
-    chosen = sorted(ENTRY_POINTS) if names is None else list(names)
-    report: dict = {}
-    for name in chosen:
-        builder = ENTRY_POINTS[name]
-        steps_out: list = []
-        t_entry = time.perf_counter()
-        with tracer.span("profile.entry-point", entry=name):
-            try:
-                plan = builder()
-            except Exception as e:
-                report[name] = {"error": repr(e), "steps": [],
-                                "compiles": 0, "wall_ms": 0.0}
-                continue
-            prev = None
-            compiles = 0
-            for label, thunk in plan.steps:
-                t0 = time.perf_counter()
-                with tracer.span("profile.step", entry=name, step=label):
-                    thunk()
-                wall_ms = (time.perf_counter() - t0) * 1e3
-                size = plan.cache_size()
-                grew = (0 if size < 0 or prev is None
-                        else max(size - prev, 0))
-                prev = size if size >= 0 else prev
-                compiles += grew
-                steps_out.append({"label": label,
-                                  "wall_ms": round(wall_ms, 3),
-                                  "cache_size": size,
-                                  "new_compiles": grew})
-                reg.histogram(f"entry.{name}.step_ms",
-                              lo=1e-3, hi=1e7).observe(wall_ms)
-        wall_ms = (time.perf_counter() - t_entry) * 1e3
-        reg.counter(f"entry.{name}.compiles").inc(compiles)
-        report[name] = {"steps": steps_out, "compiles": compiles,
-                        "wall_ms": round(wall_ms, 3)}
-    return report

@@ -19,9 +19,18 @@ Two recording modes cover the two threading shapes:
   the batch worker picks the request up — the worker records it).
 
 The span taxonomy instrumented across the stack (``route`` >
-``admission``, ``queue``, ``batch`` > ``dispatch`` > ``kernel`` >
-``rerank``, plus ``maint.*`` and ``republish``) is catalogued in
-``docs/observability.md``.
+``admission``, ``queue``; the cell worker's ``collect``, ``batch``,
+``dispatch`` > ``kernel`` > ``backend.launch``/``backend.wait``,
+``rerank``, ``deliver``; ``gc``, ``jax-compile``, ``maint.*`` and
+``republish``) is catalogued in ``docs/observability.md``.
+
+**One clock.**  Spans are timed on ``time.perf_counter``; a tracer reads
+a *clock anchor* when it is built — a ``perf_counter_ns`` and a
+``time_ns`` read together — and its timestamps count from that
+``perf_counter_ns``.  :meth:`Tracer.wall_ns` (and the exported
+``otherData.clock_anchor``) turn any span into wall-clock nanoseconds,
+the clock the JAX profiler records on, so program spans lay exactly
+over a device trace.
 
 Design constraints, inherited from the serving stack's invariants:
 
@@ -44,7 +53,24 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Optional
 
-__all__ = ["Tracer", "get_tracer", "set_tracer"]
+__all__ = ["Tracer", "clock_anchor", "get_tracer", "set_tracer"]
+
+
+_ANCHOR_READS = 5
+
+
+def clock_anchor() -> tuple:
+    """``(perf_counter_ns, time_ns)`` read together: of a few tries, the
+    wall read bracketed most tightly by two ``perf_counter_ns`` reads,
+    paired with their midpoint."""
+    best = None
+    for _ in range(_ANCHOR_READS):
+        a = time.perf_counter_ns()
+        wall = time.time_ns()
+        b = time.perf_counter_ns()
+        if best is None or b - a < best[0]:
+            best = (b - a, (a + b) // 2, wall)
+    return best[1], best[2]
 
 
 class _Span:
@@ -89,13 +115,18 @@ class Tracer:
     def __init__(self, capacity: int = 32768, enabled: bool = True):
         self.capacity = int(capacity)
         self.enabled = enabled
-        self._lock = threading.Lock()
+        # re-entrant: the garbage-collector callback (profile's
+        # install_gc_hooks) records a span on the thread whose allocation
+        # started the collection, which may hold this lock already —
+        # inside events(), allocating its copy of the ring
+        self._lock = threading.RLock()
         self._events: deque = deque(maxlen=self.capacity)
         self._n_emitted = 0
         self._ids = itertools.count(1)
         self._tls = threading.local()
-        self._t0 = time.perf_counter()
-        self._wall0 = time.time()
+        self._anchor_pc, self._anchor_wall = clock_anchor()
+        self._t0 = self._anchor_pc / 1e9
+        self._wall0 = self._anchor_wall / 1e9
 
     # -- id / context plumbing -----------------------------------------
     def new_trace_id(self) -> int:
@@ -185,6 +216,18 @@ class Tracer:
             self._events.append(ev)
             self._n_emitted += 1
 
+    # -- clock ---------------------------------------------------------
+    @property
+    def clock_anchor(self) -> dict:
+        """The ``perf_counter_ns`` that is ``ts`` 0 and the ``time_ns``
+        read with it."""
+        return {"perf_counter_ns": self._anchor_pc,
+                "time_ns": self._anchor_wall}
+
+    def wall_ns(self, ts_us: float) -> float:
+        """Wall-clock ns (``time.time_ns``'s clock) of an event's ``ts``."""
+        return self._anchor_wall + ts_us * 1e3
+
     # -- introspection / export ----------------------------------------
     @property
     def n_dropped(self) -> int:
@@ -212,13 +255,16 @@ class Tracer:
 
     def to_chrome(self) -> dict:
         """Chrome-trace JSON object: load at ui.perfetto.dev or
-        chrome://tracing.  ``ts`` is microseconds from tracer start."""
+        chrome://tracing.  ``ts`` is microseconds from tracer start, the
+        ``perf_counter_ns`` of ``otherData.clock_anchor``: an event's
+        wall-clock ns is ``clock_anchor.time_ns + ts * 1000``."""
         return {
             "traceEvents": self.events(),
             "displayTimeUnit": "ms",
             "otherData": {
                 "recorder": "repro.obs.trace",
                 "wall_time_origin_unix_s": self._wall0,
+                "clock_anchor": self.clock_anchor,
                 "events_dropped": self.n_dropped,
             },
         }

@@ -11,8 +11,14 @@ function — the *unit of replication* in the fleet tier
     handful of shapes;
   * per-request latency tracking (P50/P90/P99, queue vs compute split) in
     a fixed-footprint :class:`repro.obs.metrics.MetricsRegistry` — the
-    cell's memory does not grow with traffic — plus per-request
-    ``queue``/``batch``/``dispatch`` spans through :mod:`repro.obs.trace`;
+    cell's memory does not grow with traffic — plus spans through
+    :mod:`repro.obs.trace` that cover the batch worker's loop: ``collect``
+    (blocked waiting for a batch's first request), ``batch``,
+    ``dispatch``, ``deliver``, each tagged with the cell's collection
+    sequence number ``seq`` and, since a collection is served as one
+    dispatch per option set, the option group's index ``group``; every
+    request's ``queue`` span carries both, so (cell, seq, group) joins a
+    request to its dispatch;
   * optional hedged dispatch to a replica after ``hedge_ms`` (straggler
     mitigation inside the cell; the *fleet* hedges onto a different
     cell's mesh instead — see ``CellRouter``);
@@ -202,6 +208,8 @@ class ServingCell:
         # instruments are internally locked and never need it
         self._stats_lock = threading.Lock()
         self._stop = threading.Event()
+        # collection sequence number, written by the worker thread alone
+        self._seq = 0
         self._worker = threading.Thread(target=self._run, daemon=True)
         self._worker.start()
 
@@ -426,15 +434,26 @@ class ServingCell:
                 break
 
     # ------------------------------------------------------------------
-    def _collect(self) -> "tuple[list[_Request], float]":
-        """Returns (batch, t_first): the requests collected and the
+    def _collect(self) -> "tuple[list[_Request], float, int]":
+        """Returns (batch, t_first, seq): the requests collected, the
         instant the first one was dequeued — the micro-batch assembly
-        span runs from t_first to dispatch."""
-        try:
-            first = self.q.get(timeout=0.1)
-        except queue.Empty:
-            return [], 0.0
+        span runs from t_first to dispatch — and the collection's
+        sequence number.  The blocking wait for that first request is
+        the ``collect`` span; an empty batch means the cell was closed
+        while waiting."""
+        t_wait = time.perf_counter()
+        while True:
+            try:
+                first = self.q.get(timeout=0.1)
+                break
+            except queue.Empty:
+                if self._stop.is_set():
+                    return [], 0.0, -1
         t_first = time.perf_counter()
+        seq = self._seq
+        self._seq += 1
+        get_tracer().record_span("collect", t_wait, t_first,
+                                 cell=self.name, seq=seq)
         batch = [first]
         deadline = t_first + self.max_wait
         while len(batch) < self.max_batch:
@@ -445,11 +464,11 @@ class ServingCell:
                 batch.append(self.q.get(timeout=rem))
             except queue.Empty:
                 break
-        return batch, t_first
+        return batch, t_first, seq
 
     def _run(self):
         while not self._stop.is_set():
-            collected, t_first = self._collect()
+            collected, t_first, seq = self._collect()
             # requests abandoned by their caller (timeout) are dropped
             # here — computing them anyway would waste backend work AND
             # pollute the latency stats with latencies nobody observed
@@ -463,63 +482,79 @@ class ServingCell:
             groups: "dict[tuple, list[_Request]]" = {}
             for r in collected:
                 groups.setdefault(r.opts, []).append(r)
-            for batch in groups.values():
-                self._serve_batch(batch, t_first)
+            t_group = t_first
+            for group, batch in enumerate(groups.values()):
+                t_group = self._serve_batch(batch, t_first, t_group, seq,
+                                            group)
 
-    def _serve_batch(self, batch: "list[_Request]", t_first: float):
-            tracer = get_tracer()
-            qs = np.stack([r.query for r in batch])
-            b = qs.shape[0]
-            bb = _bucket(b)
-            if bb > b:
-                qs = np.pad(qs, ((0, bb - b), (0, 0)))
-            t0 = time.perf_counter()
-            # per-request queue waits started on the caller thread and
-            # end here, on the worker — the cross-thread recording form
-            for r in batch:
-                tracer.record_span("queue", r.t_enqueue, t_first,
-                                   trace_id=r.trace_id, cell=self.name)
-            tracer.record_span("batch", t_first, t0,
-                               trace_id=batch[0].trace_id,
-                               cell=self.name, size=b, bucket=bb)
-            try:
-                with tracer.span("dispatch",
-                                 trace_id=batch[0].trace_id,
-                                 cell=self.name, size=b, bucket=bb):
-                    result = self._dispatch(qs, self._group_kw(batch, bb))
-            except Exception as e:
-                # fail fast, keep the worker alive: every request in the
-                # batch gets a CellFailure sentinel so a router can
-                # re-dispatch it immediately instead of timing out
+    def _serve_batch(self, batch: "list[_Request]", t_first: float,
+                     t_group: float, seq: int, group: int) -> float:
+        """Serve one option group of collection ``seq``: its ``batch``
+        span starts at ``t_group``, where the group before it (if any)
+        was delivered.  Returns the instant this group was."""
+        tracer = get_tracer()
+        qs = np.stack([r.query for r in batch])
+        b = qs.shape[0]
+        bb = _bucket(b)
+        if bb > b:
+            qs = np.pad(qs, ((0, bb - b), (0, 0)))
+        t0 = time.perf_counter()
+        # per-request queue waits started on the caller thread and
+        # end here, on the worker — the cross-thread recording form
+        for r in batch:
+            tracer.record_span("queue", r.t_enqueue, t_first,
+                               trace_id=r.trace_id, cell=self.name,
+                               seq=seq, group=group)
+        tracer.record_span("batch", t_group, t0,
+                           trace_id=batch[0].trace_id, cell=self.name,
+                           size=b, bucket=bb, seq=seq, group=group)
+        try:
+            with tracer.span("dispatch",
+                             trace_id=batch[0].trace_id, cell=self.name,
+                             size=b, bucket=bb, seq=seq, group=group):
+                result = self._dispatch(qs, self._group_kw(batch, bb))
+        except Exception as e:
+            # fail fast, keep the worker alive: every request in the
+            # batch gets a CellFailure sentinel so a router can
+            # re-dispatch it immediately instead of timing out
+            with tracer.span("deliver", cell=self.name, seq=seq,
+                             group=group, failed=True):
                 self._c_failures.inc()
                 with self._stats_lock:
                     self._failure = e
                 fail = CellFailure(cell=self.name, error=e)
                 for r in batch:
                     r.future.put(fail)
-                return
-            t1 = time.perf_counter()
-            d, i = result
-            served = [(j, r) for j, r in enumerate(batch)
-                      if not r.cancelled.is_set()]   # timed out: drop
-            # telemetry BEFORE resolving futures: a caller that read its
-            # result and immediately calls stats() must see this batch
-            for _, r in served:
-                self._h_latency.observe((t1 - r.t_enqueue) * 1e3)
-                self._h_queue.observe((t_first - r.t_enqueue) * 1e3)
-            self._h_batch.observe((t0 - t_first) * 1e3)
-            self._h_dispatch.observe((t1 - t0) * 1e3)
-            self._h_bsize.observe(b)
-            with self._stats_lock:
-                self._recent_batches.append(b)
-            for j, r in served:
-                r.future.put((np.asarray(d[j]), np.asarray(i[j])))
-            if self.estimator is not None and served:
-                try:
-                    top = np.asarray(i)[:b, 0]
-                    self.estimator.observe(top)
-                except Exception:       # telemetry must never kill serving
-                    self._c_est_err.inc()
+            return time.perf_counter()
+        t1 = time.perf_counter()
+        with tracer.span("deliver", cell=self.name, seq=seq, group=group):
+            self._deliver(batch, result, t_first, t0, t1)
+        return time.perf_counter()
+
+    def _deliver(self, batch: "list[_Request]", result, t_first: float,
+                 t0: float, t1: float):
+        d, i = result
+        b = len(batch)
+        served = [(j, r) for j, r in enumerate(batch)
+                  if not r.cancelled.is_set()]   # timed out: drop
+        # telemetry BEFORE resolving futures: a caller that read its
+        # result and immediately calls stats() must see this batch
+        for _, r in served:
+            self._h_latency.observe((t1 - r.t_enqueue) * 1e3)
+            self._h_queue.observe((t_first - r.t_enqueue) * 1e3)
+        self._h_batch.observe((t0 - t_first) * 1e3)
+        self._h_dispatch.observe((t1 - t0) * 1e3)
+        self._h_bsize.observe(b)
+        with self._stats_lock:
+            self._recent_batches.append(b)
+        for j, r in served:
+            r.future.put((np.asarray(d[j]), np.asarray(i[j])))
+        if self.estimator is not None and served:
+            try:
+                top = np.asarray(i)[:b, 0]
+                self.estimator.observe(top)
+            except Exception:       # telemetry must never kill serving
+                self._c_est_err.inc()
 
     @staticmethod
     def _group_kw(batch: "list[_Request]", bb: int) -> dict:

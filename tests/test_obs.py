@@ -1,11 +1,16 @@
 """Observability tier tests: metrics registry correctness (bucket
 boundaries, quantile error bounds, thread safety, exposition round-trip),
-tracer semantics (nesting, cross-thread spans, bounded ring), the
-serving-stack integration (bounded telemetry after >10k requests, outcome
-span coverage for routed/hedged/rerouted/cancelled requests), and the
-measured-overhead bound the docs quote."""
+tracer semantics (nesting, cross-thread spans, bounded ring, the clock
+anchor against the JAX profiler's clock), the serving-stack integration
+(bounded telemetry after >10k requests, outcome span coverage for
+routed/hedged/rerouted/cancelled requests, the worker loop's spans and
+their ``seq``, the backend call's launch/wait split), the collector and
+compile hooks, and the measured-overhead bound the docs quote."""
+import gc
+import glob
 import json
 import math
+import os
 import threading
 import time
 
@@ -17,8 +22,10 @@ from repro.obs import (
     Gauge,
     Histogram,
     MetricsRegistry,
+    PROFILE,
     Tracer,
-    backend_cost,
+    install_gc_hooks,
+    install_jax_compile_hooks,
     merge_snapshots,
     parse_exposition,
     set_tracer,
@@ -281,6 +288,64 @@ class TestTracer:
             tr.instant("tick")
         assert tr.events() == []
 
+    def test_chrome_export_carries_the_clock_anchor(self):
+        tr = Tracer(capacity=16)
+        before = time.time_ns()
+        with tr.span("route"):
+            pass
+        after = time.time_ns()
+        anchor = tr.to_chrome()["otherData"]["clock_anchor"]
+        assert anchor == tr.clock_anchor
+        (ev,) = tr.events("route")
+        wall = anchor["time_ns"] + ev["ts"] * 1e3
+        assert wall == tr.wall_ns(ev["ts"])
+        assert before - 1e5 <= wall <= after + 1e5
+
+    def test_converted_span_aligns_with_a_profiler_annotation(self,
+                                                              tmp_path):
+        """A span and a ``TraceAnnotation`` around the same block start
+        and end within 100 us of each other once the span is converted
+        by the clock anchor and the annotation by the profile's start
+        time (the Task Environment plane's ``profile_start_time``).
+        Entering and leaving an annotation takes tens of us itself, so
+        the best of five blocks is held to the bound."""
+        import jax
+        from jax.profiler import ProfileData
+
+        tr = Tracer(capacity=16)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with jax.profiler.TraceAnnotation("warm-up"):
+                time.sleep(0.01)
+            for i in range(5):
+                with tr.span(f"probe{i}"), \
+                        jax.profiler.TraceAnnotation(f"probe{i}"):
+                    time.sleep(0.005)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(str(tmp_path), "**",
+                                         "*.xplane.pb"), recursive=True)
+        start = None
+        ann = {}
+        for plane in ProfileData.from_file(path).planes:
+            for key, value in plane.stats:
+                if key == "profile_start_time":
+                    start = value
+            for line in plane.lines:
+                for ev in line.events:
+                    ann[ev.name] = (ev.start_ns, ev.duration_ns)
+        assert start is not None
+        apart = []
+        for ev in tr.events():
+            a0 = start + ann[ev["name"]][0]
+            a1 = a0 + ann[ev["name"]][1]
+            apart.append(max(abs(tr.wall_ns(ev["ts"]) - a0),
+                             abs(tr.wall_ns(ev["ts"] + ev["dur"]) - a1)))
+        assert len(apart) == 5
+        assert min(apart) < 1e5, f"{min(apart) / 1e3:.1f} us apart"
+
 
 # ---------------------------------------------------------------------------
 # serving-stack integration: bounded telemetry, outcome span coverage
@@ -413,32 +478,203 @@ class TestServingIntegration:
         finally:
             router.close()
 
+    def test_worker_spans_share_seq_and_cover_the_worker(self):
+        """With a stub backend, every batch's collect/batch/dispatch/
+        deliver spans carry the seq its requests' queue spans carry, and
+        together they cover >= 95 % of the worker thread's time."""
+        from repro.serve.cell import ServingCell
+
+        def slow_fn(qs):
+            time.sleep(0.002)
+            return _ok_fn(qs)
+
+        tr = Tracer(capacity=1 << 14)
+        prev = set_tracer(tr)
+        try:
+            cell = ServingCell(slow_fn, name="c0", max_batch=8,
+                               max_wait_ms=1.0)
+            try:
+                q = np.ones(4, np.float32)
+                for burst in range(30):
+                    futs = [cell.submit(q) for _ in range(1 + burst % 11)]
+                    for f in futs:
+                        f.get(timeout=10.0)
+                    time.sleep(0.003)
+            finally:
+                cell.close()
+        finally:
+            set_tracer(prev)
+        evs = [e for e in tr.events() if e["ph"] == "X"]
+        by = {}
+        for e in evs:
+            by.setdefault(e["name"], []).append(e)
+        seqs = {n: [e["args"]["seq"] for e in by[n]]
+                for n in ("collect", "batch", "dispatch", "deliver")}
+        queued = {e["args"]["seq"] for e in by["queue"]}
+        assert len(by["queue"]) == sum(1 + b % 11 for b in range(30))
+        for n in ("batch", "dispatch", "deliver"):
+            assert sorted(seqs[n]) == sorted(queued), n
+        assert queued <= set(seqs["collect"])
+        worker = {e["tid"] for e in by["collect"]}
+        assert len(worker) == 1
+        (wtid,) = worker
+        loop = sorted((e["ts"], e["ts"] + e["dur"]) for n in seqs
+                      for e in by[n] if e["tid"] == wtid)
+        covered, end = 0.0, loop[0][0]
+        for a, b in loop:
+            if b > end:
+                covered += b - max(a, end)
+                end = b
+        extent = loop[-1][1] - loop[0][0]
+        assert covered >= 0.95 * extent, (covered, extent)
+
+    def test_option_groups_of_one_collection_share_its_seq(self):
+        """A collection served as two option groups: both dispatches
+        carry the collection's seq and their own group, each queue span
+        joins the dispatch that served it, the worker's spans never
+        overlap, and a collection of cancelled requests still takes a
+        seq of its own."""
+        from repro.serve.cell import ServingCell
+
+        def fn(qs, **kw):
+            time.sleep(0.002)
+            return _ok_fn(qs)
+
+        tr = Tracer(capacity=1 << 12)
+        prev = set_tracer(tr)
+        try:
+            cell = ServingCell(fn, name="c0", max_batch=8,
+                               max_wait_ms=50.0)
+            try:
+                q = np.ones(4, np.float32)
+                gone = threading.Event()
+                gone.set()
+                cell.submit(q, cancelled=gone)
+                time.sleep(0.1)
+                futs = [cell.submit(q), cell.submit(q, mode="hybrid"),
+                        cell.submit(q), cell.submit(q, mode="hybrid")]
+                for f in futs:
+                    f.get(timeout=10.0)
+            finally:
+                cell.close()
+        finally:
+            set_tracer(prev)
+        collects = [e["args"]["seq"] for e in tr.events("collect")]
+        assert len(collects) == len(set(collects)) >= 2
+        disp = {(e["args"]["seq"], e["args"]["group"]): e["args"]["size"]
+                for e in tr.events("dispatch")}
+        assert len(disp) == 2 and {g for _, g in disp} == {0, 1}
+        assert len({s for s, _ in disp}) == 1
+        joined = {}
+        for e in tr.events("queue"):
+            key = (e["args"]["seq"], e["args"]["group"])
+            joined[key] = joined.get(key, 0) + 1
+        assert joined == disp == {k: 2 for k in disp}
+        loop = sorted((e["ts"], e["ts"] + e["dur"]) for e in tr.events()
+                      if e["ph"] == "X" and e["name"] in
+                      ("collect", "batch", "dispatch", "deliver"))
+        for (_, end), (start, _) in zip(loop, loop[1:]):
+            assert start >= end - 1e-3
+
+    def test_backend_launch_and_wait_nest_inside_kernel(self):
+        from repro.distributed.backend import ShardedSearchBackend
+        from repro.launch.mesh import make_mesh
+
+        rng = np.random.default_rng(0)
+        db = rng.normal(size=(256, 16)).astype(np.float32)
+        be = ShardedSearchBackend(make_mesh((1,), ("data",)), db, k=5,
+                                  axes=("data",))
+        be(db[:4])                                  # compile outside
+        tr = Tracer(capacity=64)
+        prev = set_tracer(tr)
+        try:
+            be(db[:4])
+        finally:
+            set_tracer(prev)
+        (kernel,) = tr.events("kernel")
+        for name in ("backend.launch", "backend.wait"):
+            (ev,) = tr.events(name)
+            assert ev["args"]["parent"] == kernel["args"]["span_id"]
+            assert kernel["ts"] <= ev["ts"]
+            assert ev["ts"] + ev["dur"] <= \
+                kernel["ts"] + kernel["dur"] + 1e-3
+        launch, wait = tr.events("backend.launch")[0], \
+            tr.events("backend.wait")[0]
+        assert launch["ts"] + launch["dur"] <= wait["ts"] + 1e-3
+
 
 # ---------------------------------------------------------------------------
-# profiling: analytic cost model + overhead bound
+# profiling: compile and collector hooks, overhead bound
 # ---------------------------------------------------------------------------
 
 
 class TestProfiling:
-    def test_backend_cost_fused_vs_unfused_vs_int8(self):
-        kw = dict(n_rows=100_000, d=128, b=64, k=10)
-        fused = backend_cost("brute", fused=True, precision="f32", **kw)
-        unfused = backend_cost("brute", fused=False, precision="f32", **kw)
-        int8 = backend_cost("brute", fused=True, precision="int8", **kw)
-        # same useful bytes, unfused pays the (B, N) materialization
-        # (write + read-back) on top
-        assert fused["useful_bytes"] == unfused["useful_bytes"]
-        assert unfused["bytes_moved"] - fused["bytes_moved"] == \
-            2 * 64 * 100_000 * 4
-        assert fused["analytic_frac"] > 0.99 > unfused["analytic_frac"]
-        # int8 moves ~1/4 the corpus bytes of f32
-        assert int8["useful_bytes"] < 0.3 * fused["useful_bytes"]
-        assert not fused["estimate"]
-        ivf = backend_cost("ivf", fused=True, precision="f32",
-                           n_rows=100_000, d=128, b=64, k=10,
-                           n_probe_rows=8000, n_centroids=64)
-        assert ivf["estimate"] and \
-            ivf["useful_bytes"] < fused["useful_bytes"]
+    @staticmethod
+    def _count(name):
+        m = PROFILE.get(name)
+        return 0 if m is None else (m.value if m.kind == "counter"
+                                    else m.count)
+
+    def test_full_collection_is_one_gc_span_and_counted(self):
+        assert install_gc_hooks() and install_gc_hooks()   # idempotent
+        tr = Tracer(capacity=64)
+        prev = set_tracer(tr)
+        was_enabled = gc.isenabled()
+        gc.disable()                # no automatic collection in between
+        try:
+            n2 = self._count("gc_collections.gen2")
+            pauses = self._count("gc_pause_ms")
+            gc.collect(2)
+        finally:
+            if was_enabled:
+                gc.enable()
+            set_tracer(prev)
+        (ev,) = tr.events("gc")
+        assert ev["ph"] == "X" and ev["dur"] > 0
+        assert ev["args"]["generation"] == 2
+        assert ev["args"]["collected"] >= 0
+        assert ev["tid"] == threading.get_ident()
+        assert self._count("gc_collections.gen2") == n2 + 1
+        assert self._count("gc_pause_ms") == pauses + 1
+        assert "gc_collections_gen2" in PROFILE.exposition()
+
+    def test_young_collections_are_counted_without_spans(self):
+        install_gc_hooks()
+        tr = Tracer(capacity=64)
+        prev = set_tracer(tr)
+        was_enabled = gc.isenabled()
+        gc.disable()
+        try:
+            n0 = self._count("gc_collections.gen0")
+            pauses = self._count("gc_pause_ms")
+            gc.collect(0)
+        finally:
+            if was_enabled:
+                gc.enable()
+            set_tracer(prev)
+        assert self._count("gc_collections.gen0") == n0 + 1
+        assert self._count("gc_pause_ms") == pauses
+        assert tr.events("gc") == []
+
+    def test_a_compile_is_a_span_of_its_duration(self):
+        import jax
+
+        assert install_jax_compile_hooks()
+        tr = Tracer(capacity=256)
+        prev = set_tracer(tr)
+        try:
+            def oddly_named_fn(x):
+                return x * 3 + 1
+
+            jax.jit(oddly_named_fn)(np.arange(7.0)).block_until_ready()
+        finally:
+            set_tracer(prev)
+        spans = [e for e in tr.events("jax-compile")
+                 if "oddly_named_fn" in e["args"]["fun"]]
+        assert spans and all(e["ph"] == "X" and e["dur"] > 0
+                             for e in spans)
+        assert "backend_compile_duration" in {e["args"]["stage"]
+                                              for e in spans}
 
     def test_measured_overhead_bound(self):
         """The docs claim sub-10us per traced span / observed sample;
