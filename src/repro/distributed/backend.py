@@ -61,6 +61,7 @@ from repro.distributed.sharding import (
     _axes_size,
     _brute_device_arrays,
     _brute_int8_device_arrays,
+    _bucket_width,
     _forest_device_arrays,
     _ivf_device_arrays,
     _lexical_device_arrays,
@@ -192,8 +193,12 @@ class ShardedSearchBackend:
                 mesh, self.axes, k, self._rows, self.query_axes,
                 fused=fused, precision=precision))
         elif kind == "ivf":
-            self._K = int(target.bucket_ids.shape[0])
-            self._cap = int(np.ceil(target.bucket_ids.shape[1] * headroom))
+            self._K, width = (int(s) for s in target.bucket_ids.shape)
+            self._cap = _bucket_width(width, headroom)
+            # set once at placement: the reserved width, and the pad slots
+            # it adds to every bucket beyond the index's own width
+            self.metrics.gauge("ivf_bucket_width").set(self._cap)
+            self.metrics.gauge("ivf_bucket_pad_slots").set(self._cap - width)
             Kp = -(-self._K // self.n_dev) * self.n_dev
             self._Kp = Kp
             self._fn = jax.jit(make_sharded_ivf_fn(

@@ -556,16 +556,28 @@ def make_sharded_ivf_fn(mesh, axes: tuple, k: int, nprobe_local: int,
     )
 
 
+def _bucket_width(width: int, headroom: float = 1.0, dtype=np.float32) -> int:
+    """Bucket width to reserve for ``width`` rows with ``headroom``: rounded
+    up to the sublane tile of ``dtype`` (8 rows of 32-bit data).  At an
+    unaligned width the TPU compiler lays the ``(Kloc, cap, d)`` bucket
+    tensor out cap-major to skip the padding, and the probe loop then
+    re-lays the whole tensor out row-major on every call."""
+    sublane = 8 * 4 // np.dtype(dtype).itemsize
+    return -(-int(np.ceil(width * headroom)) // sublane) * sublane
+
+
 def _ivf_device_arrays(index, n_dev, cap=None):
     """Pad a built TwoLevelIndex's centroid/bucket tables to the shard grid
     (zero vectors, -1 ids — pads are masked by index, never by inf).
     ``cap`` pads the bucket width beyond the index's own (update headroom:
     a mutated index re-places into the same shapes, so the jitted search
-    is reused).  The bucket gather runs on the host: the tensor is several
-    times the corpus (deep-10m: ~10 GB) and goes straight to its shards."""
+    is reused); by default the index's width rounded up to the sublane
+    tile (:func:`_bucket_width`).  The bucket gather runs on the host: the
+    tensor is several times the corpus (deep-10m: ~10 GB) and goes
+    straight to its shards."""
     K, cap_now = index.bucket_ids.shape
     if cap is None:
-        cap = cap_now
+        cap = _bucket_width(cap_now)
     if cap < cap_now:
         raise ValueError(
             f"bucket cap grew to {cap_now} > reserved {cap}; rebuild the "

@@ -18,6 +18,7 @@ one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -64,6 +65,7 @@ def tpu_dispatch(monkeypatch):
 def _compile(fn, *args):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, "the Pallas kernel is not in the program"
+    return text
 
 
 def _shape(sharding, shape, dtype=jnp.float32):
@@ -144,7 +146,7 @@ def _served(topo, n_dev, kind, n, d, *, n_buckets=0, cap=0, nprobe=32,
         args = (_shape(spec("data", None), (kp, d)),
                 _shape(spec("data", None), (kp, cap), jnp.int32),
                 _shape(spec("data", None, None), (kp, cap, d)), q)
-    _compile(fn, *args)
+    return _compile(fn, *args)
 
 
 @pytest.mark.parametrize("kind,precision", [
@@ -157,3 +159,34 @@ def test_served_sift_1m_one_chip(topo, tpu_dispatch, kind, precision):
 @pytest.mark.parametrize("kind", ["ivf", "brute"])
 def test_served_deep_10m_four_chips(topo, tpu_dispatch, kind):
     _served(topo, 4, kind, 10_000_000, 96, n_buckets=32768, cap=763)
+
+
+def _entry_param(text, i):
+    """Name and entry layout of the compiled program's ``i``-th parameter."""
+    head = text.splitlines()[0]
+    layouts = re.findall(r"\w+\[[\d,]*\]\{[^}]*\}", re.search(
+        r"entry_computation_layout=\{\((.*?)\)->", head).group(1))
+    entry = re.search(r"^ENTRY %\S+ \((.*?)\) ->", text, re.M).group(1)
+    names = re.findall(r"([\w.]+): \w+\[", entry)
+    return names[i], layouts[i]
+
+
+@pytest.mark.parametrize("n_dev,n_buckets,width,d,layout", [
+    (1, 8192, 306, 128, "{2,1,0:"),    # sift-1m on one chip
+    # deep-10m on four: at d = 96 the compiler keeps the bucket width in
+    # lanes, and the probe loop reads the tensor in that layout as placed
+    (4, 32768, 763, 96, "{1,2,0:"),
+])
+def test_served_ivf_reserved_width_keeps_bucket_layout(
+        topo, tpu_dispatch, n_dev, n_buckets, width, d, layout):
+    """At the width the backend reserves for an unaligned index width, the
+    bucket-vector tensor enters the program in the layout its probe loop
+    reads: no per-call copy of the whole tensor."""
+    from repro.distributed.sharding import _bucket_width
+
+    cap = _bucket_width(width)
+    text = _served(topo, n_dev, "ivf", 0, d, n_buckets=n_buckets, cap=cap)
+    name, entry_layout = _entry_param(text, 2)
+    assert entry_layout.startswith(f"f32[{-(-n_buckets // n_dev)},{cap},{d}]")
+    assert f"copy(%{name})" not in text, "the bucket tensor is re-laid out"
+    assert layout in entry_layout, entry_layout

@@ -243,6 +243,94 @@ def test_slice_forest_delta_matches_full_slab_slice():
         assert pay["roots"][u] == full["roots"][s, j]
 
 
+@pytest.mark.parametrize("width,headroom,dtype,want", [
+    (306, 1.0, np.float32, 312),     # sift-1m's built width
+    (312, 1.0, np.float32, 312),     # already on the tile
+    (763, 1.0, np.float32, 768),     # deep-10m's built width
+    (306, 1.5, np.float32, 464),     # headroom first, then the tile
+    (306, 1.0, np.int32, 312),
+    (306, 1.0, np.float16, 320),     # 16-bit data: a 16-row tile
+    (306, 1.0, np.int8, 320),        # 8-bit data: a 32-row tile
+])
+def test_bucket_width_rounds_up_to_sublane_tile(width, headroom, dtype, want):
+    from repro.distributed.sharding import _bucket_width
+
+    assert _bucket_width(width, headroom, dtype) == want
+
+
+def test_ivf_backend_reserves_tile_aligned_width():
+    """An IVF index whose width is off the 8-row tile is placed at the
+    rounded width: same answers as the width left as built, and a bucket
+    delta ships payloads of the placed shapes."""
+    import jax
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core.two_level import TwoLevelConfig, build_two_level
+    from repro.distributed.backend import ShardedSearchBackend
+    from repro.distributed.sharding import (
+        _ivf_device_arrays,
+        make_sharded_ivf_fn,
+        slice_ivf_delta,
+    )
+    from repro.launch.mesh import make_mesh
+
+    rng = np.random.default_rng(0)
+    c = rng.normal(size=(16, 16)) * 4
+
+    def mk(n):
+        return (c[rng.integers(0, 16, n)]
+                + rng.normal(size=(n, 16))).astype(np.float32)
+
+    idx = build_two_level(mk(1500), TwoLevelConfig(
+        n_clusters=24, top="brute", bottom="brute", kmeans_iters=4))
+    n_buckets, width = idx.bucket_ids.shape
+    assert width % 8, "the case needs a width off the tile"
+    cap = -(-width // 8) * 8
+    mesh = make_mesh((1,), ("data",))
+    be = ShardedSearchBackend(mesh, idx, kind="ivf", k=10, nprobe_local=6,
+                              axes=("data",))
+    assert be._cap == cap
+    assert be._args[1].shape == (n_buckets, cap)
+    assert be._args[2].shape == (n_buckets, cap, 16)
+    assert be.metrics.get("ivf_bucket_width").value == cap
+    assert be.metrics.get("ivf_bucket_pad_slots").value == cap - width
+
+    q = mk(32)
+    cents, bids, bvecs, kp = _ivf_device_arrays(idx, 1, cap=width)
+    assert bvecs.shape == (n_buckets, width, 16)
+    built = jax.jit(make_sharded_ivf_fn(mesh, ("data",), 10, 6, kp,
+                                        n_buckets))
+    put = lambda x, *spec: jax.device_put(x, NamedSharding(mesh, P(*spec)))
+    with mesh:
+        d0, i0 = jax.device_get(built(
+            put(cents, "data", None), put(bids, "data", None),
+            put(bvecs, "data", None, None), put(q, None, None)))
+    d1, i1 = be(q)
+    np.testing.assert_array_equal(i1, i0)
+    np.testing.assert_array_equal(d1, d0)
+
+    # grow buckets below the reserved width: a delta of the placed shapes
+    idx.add_entities(mk(12))
+    assert idx.bucket_ids.shape[1] <= cap
+    man = idx.pop_delta()
+    pay = slice_ivf_delta(idx, be._cap, man.dirty_buckets)
+    assert pay["bucket_ids"].shape[1:] == be._args[1].shape[1:]
+    assert pay["bvecs"].shape[1:] == be._args[2].shape[1:]
+    st = be.apply_updates(idx, delta=man)
+    assert st["mode"] == "delta"
+    assert [a.shape for a in be._args] == [(n_buckets, 16), (n_buckets, cap),
+                                           (n_buckets, cap, 16)]
+    fresh = ShardedSearchBackend(mesh, idx, kind="ivf", k=10,
+                                 nprobe_local=6, axes=("data",))
+    for x, y in zip(be._args, fresh._args):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    d2, i2 = be(q)
+    d3, i3 = fresh(q)
+    np.testing.assert_array_equal(i2, i3)
+    np.testing.assert_array_equal(d2, d3)
+
+
 # ---------------------------------------------------------------------------
 # slow, subprocess: real 8-device semantics
 # ---------------------------------------------------------------------------
