@@ -18,7 +18,10 @@ function — the *unit of replication* in the fleet tier
     sequence number ``seq`` and, since a collection is served as one
     dispatch per option set, the option group's index ``group``; every
     request's ``queue`` span carries both, so (cell, seq, group) joins a
-    request to its dispatch;
+    request to its dispatch; ``collect`` and ``dispatch`` also carry
+    ``device``, the id of the chip the backend's mesh holds (a tuple of
+    ids for a mesh of several), so a device trace pairs each chip with
+    its own worker;
   * optional hedged dispatch to a replica after ``hedge_ms`` (straggler
     mitigation inside the cell; the *fleet* hedges onto a different
     cell's mesh instead — see ``CellRouter``);
@@ -146,6 +149,16 @@ class EngineStats:
     stages: "dict | None" = None
 
 
+def _mesh_device(search_fn):
+    """The id of the chip ``search_fn``'s mesh holds, a tuple of ids
+    where it holds several, or None for a backend without a mesh."""
+    mesh = getattr(search_fn, "mesh", None)
+    if mesh is None:
+        return None
+    ids = tuple(int(d.id) for d in mesh.devices.flat)
+    return ids[0] if len(ids) == 1 else ids
+
+
 def _bucket(n: int) -> int:
     b = 1
     while b < n:
@@ -178,6 +191,7 @@ class ServingCell:
         routing keeps each cell's head coherent)."""
         self.search_fn = search_fn
         self.name = name
+        self.device = _mesh_device(search_fn)
         self.hedge_fn = hedge_fn
         self.hedge_ms = hedge_ms
         self.cache = cache
@@ -453,7 +467,8 @@ class ServingCell:
         seq = self._seq
         self._seq += 1
         get_tracer().record_span("collect", t_wait, t_first,
-                                 cell=self.name, seq=seq)
+                                 cell=self.name, seq=seq,
+                                 device=self.device)
         batch = [first]
         deadline = t_first + self.max_wait
         while len(batch) < self.max_batch:
@@ -511,7 +526,8 @@ class ServingCell:
         try:
             with tracer.span("dispatch",
                              trace_id=batch[0].trace_id, cell=self.name,
-                             size=b, bucket=bb, seq=seq, group=group):
+                             size=b, bucket=bb, seq=seq, group=group,
+                             device=self.device):
                 result = self._dispatch(qs, self._group_kw(batch, bb))
         except Exception as e:
             # fail fast, keep the worker alive: every request in the

@@ -576,6 +576,50 @@ class TestServingIntegration:
         for (_, end), (start, _) in zip(loop, loop[1:]):
             assert start >= end - 1e-3
 
+    def test_collect_and_dispatch_name_the_backend_device(self):
+        """``device`` is read once from the backend's mesh: the chip's id
+        for one chip, the ids for several, None for a plain callable;
+        every collect and dispatch span carries it."""
+        from types import SimpleNamespace
+
+        import jax
+
+        from repro.distributed.backend import ShardedSearchBackend
+        from repro.launch.mesh import make_mesh
+        from repro.serve.cell import ServingCell
+
+        db = np.random.default_rng(0).normal(size=(64, 4)).astype(
+            np.float32)
+        be = ShardedSearchBackend(make_mesh((1,), ("data",)), db, k=3,
+                                  axes=("data",))
+        dev = jax.devices()[0]
+
+        def wide(qs):
+            return _ok_fn(qs)
+
+        wide.mesh = SimpleNamespace(
+            devices=np.array([SimpleNamespace(id=5), SimpleNamespace(id=6)]))
+        tr = Tracer(capacity=1 << 10)
+        prev = set_tracer(tr)
+        try:
+            cells = [ServingCell(be, name="mesh", max_wait_ms=0.5),
+                     ServingCell(wide, name="wide", max_wait_ms=0.5),
+                     ServingCell(_ok_fn, name="bare", max_wait_ms=0.5)]
+            try:
+                for c in cells:
+                    c.submit(db[0]).get(timeout=30.0)
+            finally:
+                for c in cells:
+                    c.close()
+        finally:
+            set_tracer(prev)
+        assert [c.device for c in cells] == [dev.id, (5, 6), None]
+        want = {"mesh": dev.id, "wide": (5, 6), "bare": None}
+        for name in ("collect", "dispatch"):
+            got = {e["args"]["cell"]: e["args"]["device"]
+                   for e in tr.events(name)}
+            assert got == want
+
     def test_backend_launch_and_wait_nest_inside_kernel(self):
         from repro.distributed.backend import ShardedSearchBackend
         from repro.launch.mesh import make_mesh
