@@ -6,9 +6,19 @@ datacenter deployment batches concurrent queries instead.  A
 function — the *unit of replication* in the fleet tier
 (:mod:`repro.serve.fleet` routes across many cells on disjoint meshes):
 
-  * micro-batching: collect up to ``max_batch`` requests or ``max_wait_ms``
-    (whichever first), pad to the next power-of-two bucket so jit caches a
-    handful of shapes;
+  * micro-batching: when the worker takes a batch's first request it
+    takes every request already queued, up to ``max_batch``, without
+    waiting; requests that arrive while a batch is served queue for the
+    next collection, so a slow cycle grows the next batch.  Then, by
+    default (``max_wait_ms`` 0), it holds the batch open only while the
+    batch holds fewer requests than are due, at the arrival rate the
+    worker has observed, in the rest of one serve time (its measured
+    time from the end of a hold to delivery), so a light load is
+    dispatched at once and a dispatch's fixed cost is not paid for one
+    request when the next is due before it would be served.  A positive
+    ``max_wait_ms`` instead holds every batch until ``max_wait_ms`` after
+    its first request or ``max_batch`` requests.  The batch is padded to
+    the next power-of-two bucket so jit caches a handful of shapes;
   * per-request latency tracking (P50/P90/P99, queue vs compute split) in
     a fixed-footprint :class:`repro.obs.metrics.MetricsRegistry` — the
     cell's memory does not grow with traffic — plus spans through
@@ -169,13 +179,16 @@ def _bucket(n: int) -> int:
 class ServingCell:
     """search_fn(queries (B, d)) -> (dists (B,k), ids (B,k))."""
 
+    # weight of the newest collection in the hold's moving averages
+    _EWMA = 1.0 / 16
+
     def __init__(
         self,
         search_fn: Callable,
         *,
         name: str = "cell0",
         max_batch: int = 64,
-        max_wait_ms: float = 2.0,
+        max_wait_ms: float = 0.0,
         hedge_fn: Optional[Callable] = None,
         hedge_ms: float = 50.0,
         cache=None,
@@ -208,6 +221,10 @@ class ServingCell:
         self._h_dispatch = self.metrics.histogram("dispatch_ms")
         self._h_bsize = self.metrics.histogram("batch_size", lo=1.0,
                                                hi=4096.0)
+        # dispatched requests the drain took from the backlog, each
+        # collection's first among them; the rest of batch_size's sum
+        # joined during a hold, so the two say how often holds engage
+        self._c_backlog = self.metrics.counter("batched_from_backlog")
         self._c_hedges = self.metrics.counter("hedges")
         self._c_cancelled = self.metrics.counter("cancelled")
         self._c_repub = self.metrics.counter("republished_bytes")
@@ -224,6 +241,12 @@ class ServingCell:
         self._stop = threading.Event()
         # collection sequence number, written by the worker thread alone
         self._seq = 0
+        # moving averages of the worker's own collections, written by
+        # the worker alone (see _observe): requests a collection, seconds
+        # between collections' first requests, their ratio, and seconds
+        # from the end of a hold to its delivery
+        self._t_last: Optional[float] = None
+        self._n_avg = self._gap_avg = self._rate = self._serve_s = 0.0
         self._worker = threading.Thread(target=self._run, daemon=True)
         self._worker.start()
 
@@ -448,13 +471,15 @@ class ServingCell:
                 break
 
     # ------------------------------------------------------------------
-    def _collect(self) -> "tuple[list[_Request], float, int]":
-        """Returns (batch, t_first, seq): the requests collected, the
-        instant the first one was dequeued — the micro-batch assembly
-        span runs from t_first to dispatch — and the collection's
-        sequence number.  The blocking wait for that first request is
-        the ``collect`` span; an empty batch means the cell was closed
-        while waiting."""
+    def _collect(self) -> "tuple[list[_Request], float, int, int]":
+        """Returns (batch, t_first, seq, drained): the requests
+        collected, the instant the first one was dequeued — the
+        micro-batch assembly span runs from t_first to dispatch — the
+        collection's sequence number, and how many of the batch's
+        leading requests the drain took without waiting (the first
+        among them).  The blocking wait for that first request is the
+        ``collect`` span; an empty batch means the cell was closed while
+        waiting."""
         t_wait = time.perf_counter()
         while True:
             try:
@@ -462,7 +487,7 @@ class ServingCell:
                 break
             except queue.Empty:
                 if self._stop.is_set():
-                    return [], 0.0, -1
+                    return [], 0.0, -1, 0
         t_first = time.perf_counter()
         seq = self._seq
         self._seq += 1
@@ -470,26 +495,45 @@ class ServingCell:
                                  cell=self.name, seq=seq,
                                  device=self.device)
         batch = [first]
-        deadline = t_first + self.max_wait
         while len(batch) < self.max_batch:
-            rem = deadline - time.perf_counter()
+            try:
+                batch.append(self.q.get_nowait())
+            except queue.Empty:
+                break
+        drained = len(batch)
+        while len(batch) < self.max_batch:
+            if self.max_wait > 0:
+                rem = t_first + self.max_wait - time.perf_counter()
+            elif self._rate > 0:
+                # hold while the batch holds fewer requests than are
+                # due, at the observed rate, in the rest of one serve
+                # time: till then a request that joins gains more (it
+                # skips waiting out this dispatch) than the batch's
+                # requests lose by waiting for it
+                rem = (t_first + self._serve_s - time.perf_counter()
+                       - len(batch) / self._rate)
+            else:
+                break
             if rem <= 0:
                 break
             try:
                 batch.append(self.q.get(timeout=rem))
             except queue.Empty:
                 break
-        return batch, t_first, seq
+        return batch, t_first, seq, drained
 
     def _run(self):
         while not self._stop.is_set():
-            collected, t_first, seq = self._collect()
+            collected, t_first, seq, drained = self._collect()
             # requests abandoned by their caller (timeout) are dropped
             # here — computing them anyway would waste backend work AND
             # pollute the latency stats with latencies nobody observed
-            collected = [r for r in collected if not r.cancelled.is_set()]
+            live = [not r.cancelled.is_set() for r in collected]
+            drained = sum(live[:drained])
+            collected = [r for r, ok in zip(collected, live) if ok]
             if not collected:
                 continue
+            self._c_backlog.inc(drained)
             # one backend dispatch carries one filter/mode/alpha, so a
             # collected batch is served as one group per distinct option
             # set; default semantic requests all share the () group and
@@ -497,16 +541,32 @@ class ServingCell:
             groups: "dict[tuple, list[_Request]]" = {}
             for r in collected:
                 groups.setdefault(r.opts, []).append(r)
-            t_group = t_first
+            t_held, t_group = time.perf_counter(), t_first
             for group, batch in enumerate(groups.values()):
                 t_group = self._serve_batch(batch, t_first, t_group, seq,
-                                            group)
+                                            group, drained)
+            self._observe(len(collected), t_first, t_group - t_held)
+
+    def _observe(self, n: int, t_first: float, serve_s: float):
+        """Fold one collection into the moving averages the hold reads:
+        its ``n`` requests over the time since the previous collection's
+        first request (the arrival rate), and ``serve_s``, the time from
+        the end of its hold to its delivery."""
+        a = self._EWMA
+        if self._t_last is not None:
+            self._n_avg += a * (n - self._n_avg)
+            self._gap_avg += a * (t_first - self._t_last - self._gap_avg)
+            self._rate = self._n_avg / self._gap_avg
+        self._t_last = t_first
+        self._serve_s += a * (serve_s - self._serve_s)
 
     def _serve_batch(self, batch: "list[_Request]", t_first: float,
-                     t_group: float, seq: int, group: int) -> float:
+                     t_group: float, seq: int, group: int,
+                     drained: int) -> float:
         """Serve one option group of collection ``seq``: its ``batch``
         span starts at ``t_group``, where the group before it (if any)
-        was delivered.  Returns the instant this group was."""
+        was delivered, and carries the collection's ``drained`` count.
+        Returns the instant this group was."""
         tracer = get_tracer()
         qs = np.stack([r.query for r in batch])
         b = qs.shape[0]
@@ -522,7 +582,8 @@ class ServingCell:
                                seq=seq, group=group)
         tracer.record_span("batch", t_group, t0,
                            trace_id=batch[0].trace_id, cell=self.name,
-                           size=b, bucket=bb, seq=seq, group=group)
+                           size=b, bucket=bb, seq=seq, group=group,
+                           drained=drained)
         try:
             with tracer.span("dispatch",
                              trace_id=batch[0].trace_id, cell=self.name,

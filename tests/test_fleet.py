@@ -3,12 +3,14 @@ admission/affinity/hedging/rerouting, leader fan-out, and submesh
 partitioning.  Conformance (bitwise router-vs-engine and fan-out
 parity) lives in test_conformance.py; these tests cover the *behavior*
 the fleet adds on top of a correct cell."""
+import queue
 import threading
 import time
 
 import numpy as np
 import pytest
 
+from repro.obs.trace import Tracer, set_tracer
 from repro.serve.cell import CellFailure, ServingCell
 from repro.serve.fleet import CellRouter, FleetOverloadError, build_fleet
 
@@ -106,14 +108,195 @@ def test_cell_close_fails_queued_requests():
 
 
 # ---------------------------------------------------------------------------
+# cell: work-conserving micro-batching (drain the backlog, hold on request)
+# ---------------------------------------------------------------------------
+
+
+class _Gated:
+    """A backend whose calls block until ``gate`` is set; ``entered``
+    is set once a call is inside, so the worker is known to be busy."""
+
+    def __init__(self):
+        self.gate = threading.Event()
+        self.entered = threading.Event()
+
+    def __call__(self, qs):
+        self.entered.set()
+        self.gate.wait(10.0)
+        return _ok_fn(qs)
+
+
+class _HoldProbe(queue.Queue):
+    """A cell queue that sets ``holding`` when the worker, having
+    drained it empty, blocks on it again: the batch's hold."""
+
+    def __init__(self):
+        super().__init__()
+        self.holding = threading.Event()
+        self._drained_empty = False
+
+    def get(self, block=True, timeout=None):
+        if block and self._drained_empty:
+            self.holding.set()
+        try:
+            item = super().get(block, timeout)
+        except queue.Empty:
+            self._drained_empty = not block
+            raise
+        self._drained_empty = False
+        return item
+
+
+def _serve_backlog(n, *, cancel=(), **cell_kw):
+    """Hold the worker inside the backend on a first request, queue
+    ``n`` requests behind it (those at indices ``cancel`` abandoned by
+    their caller), release it; returns the cell's batch sizes in order,
+    its ``batched_from_backlog`` count and the ``drained`` attribute of
+    each ``batch`` span."""
+    fn = _Gated()
+    tr = Tracer(capacity=1 << 10)
+    prev = set_tracer(tr)
+    cell = ServingCell(fn, name="c", **cell_kw)
+    try:
+        q = np.ones(4, np.float32)
+        first = cell.submit(q)
+        assert fn.entered.wait(10.0)
+        futs = []
+        for j in range(n):
+            gone = threading.Event()
+            if j in cancel:
+                gone.set()
+            futs.append(cell.submit(q, cancelled=gone))
+        fn.gate.set()
+        first.get(timeout=10.0)
+        for j, f in enumerate(futs):
+            if j not in cancel:
+                f.get(timeout=10.0)
+        return (cell.stats().batch_sizes,
+                cell.metrics.get("batched_from_backlog").value,
+                [e["args"]["drained"] for e in tr.events("batch")])
+    finally:
+        cell.close()
+        set_tracer(prev)
+
+
+@pytest.mark.parametrize("cell_kw", [{}, {"max_wait_ms": 0.0}],
+                         ids=["default", "no-hold"])
+def test_cell_drains_the_backlog_into_one_batch(cell_kw):
+    """Requests queued while the worker is busy all ride its next batch,
+    with no ``max_wait_ms`` and no arrival rate observed yet, without a
+    wait for more and without a dispatch of one alone."""
+    sizes, _, _ = _serve_backlog(5, max_batch=8, **cell_kw)
+    assert sizes == [1, 5]
+
+
+def test_cell_drain_stops_at_max_batch():
+    sizes, from_backlog, drained = _serve_backlog(20, max_batch=8)
+    assert sizes == [1, 8, 8, 4]
+    assert drained == sizes and from_backlog == 21
+
+
+def test_cell_counts_the_requests_drained_from_the_backlog():
+    """``drained`` on the ``batch`` span and the ``batched_from_backlog``
+    counter count the dispatched requests the drain took, the first of
+    each collection among them, and not an abandoned one."""
+    sizes, from_backlog, drained = _serve_backlog(6, cancel={2},
+                                                  max_batch=8)
+    assert sizes == [1, 5]
+    assert drained == [1, 5]
+    assert from_backlog == 6
+
+
+def test_cell_hold_admits_a_request_submitted_after_the_drain():
+    """A positive ``max_wait_ms`` still holds the batch open after the
+    drain: a request submitted during the hold joins it, and the hold
+    ends once the batch reaches ``max_batch``."""
+    tr = Tracer(capacity=1 << 10)
+    prev = set_tracer(tr)
+    cell = ServingCell(_ok_fn, name="c", max_batch=2, max_wait_ms=60e3)
+    probe = cell.q = _HoldProbe()
+    try:
+        q = np.ones(4, np.float32)
+        fa = cell.submit(q)
+        assert probe.holding.wait(10.0)
+        fb = cell.submit(q)
+        fa.get(timeout=10.0)
+        fb.get(timeout=10.0)
+        assert cell.stats().batch_sizes == [2]
+        (span,) = tr.events("batch")
+        assert span["args"]["size"] == 2 and span["args"]["drained"] == 1
+        assert cell.metrics.get("batched_from_backlog").value == 1
+    finally:
+        cell.close()
+        set_tracer(prev)
+
+
+def _observed_cell(rate_per_s, serve_s):
+    """A cell whose worker has observed ``rate_per_s`` arrivals and a
+    ``serve_s`` serve time, with its queue behind a hold probe."""
+    cell = ServingCell(_ok_fn, name="c", max_batch=2)
+    cell._rate, cell._serve_s = rate_per_s, serve_s
+    probe = cell.q = _HoldProbe()
+    return cell, probe
+
+
+def test_cell_holds_a_batch_below_the_arrivals_of_one_serve_time():
+    """With no ``max_wait_ms`` the cell holds a batch while it carries
+    fewer requests than arrive, at the observed rate, in one serve time:
+    a request submitted during that hold joins the batch."""
+    cell, probe = _observed_cell(1000.0, 60.0)
+    try:
+        q = np.ones(4, np.float32)
+        fa = cell.submit(q)
+        assert probe.holding.wait(10.0)
+        fb = cell.submit(q)
+        fa.get(timeout=10.0)
+        fb.get(timeout=10.0)
+        assert cell.stats().batch_sizes == [2]
+        assert cell.metrics.get("batched_from_backlog").value == 1
+    finally:
+        cell.close()
+
+
+def test_cell_does_not_hold_where_under_one_request_arrives_a_serve_time():
+    """Where less than one request arrives in a serve time (light load),
+    a batch of one is dispatched at once: answered well inside the 60 s
+    a hold would last."""
+    cell, _ = _observed_cell(0.01, 60.0)
+    try:
+        cell.submit(np.ones(4, np.float32)).get(timeout=10.0)
+        assert cell.stats().batch_sizes == [1]
+    finally:
+        cell.close()
+
+
+def test_cell_observes_its_arrival_rate_and_serve_time():
+    """The hold's moving averages follow the worker's own collections:
+    requests over the time between their first requests, and the time
+    from the end of a hold to the delivery."""
+    cell = ServingCell(_ok_fn, name="c")
+    cell.close()
+    for j in range(200):
+        cell._observe(4, 0.01 * j, 0.002)
+    assert cell._rate == pytest.approx(400.0, rel=1e-4)
+    assert cell._serve_s == pytest.approx(0.002, rel=1e-4)
+    for j in range(200, 400):
+        cell._observe(1, 0.01 * j, 0.004)
+    assert cell._rate == pytest.approx(100.0, rel=1e-4)
+    assert cell._serve_s == pytest.approx(0.004, rel=1e-4)
+
+
+# ---------------------------------------------------------------------------
 # router: admission, affinity, hedging, rerouting
 # ---------------------------------------------------------------------------
 
 
 def test_router_admission_sheds_with_retriable_signal():
     gate = threading.Event()
+    entered = threading.Event()
 
     def blocked(qs):
+        entered.set()
         gate.wait(60.0)       # generous: a loaded CI box must not let
         return _ok_fn(qs)     # the queue drain before the shed probe
 
@@ -126,7 +309,11 @@ def test_router_admission_sheds_with_retriable_signal():
                     np.full(4, j, np.float32), timeout=90.0),
                 daemon=True)
             for j in range(3)]                # 1 in compute + 2 queued
-        for t in threads:
+        # the first request must be in compute before the others are
+        # admitted, or the third sees a depth of 2 and is shed itself
+        threads[0].start()
+        assert entered.wait(15.0)
+        for t in threads[1:]:
             t.start()
         deadline = time.perf_counter() + 15.0
         while cell.depth() < 2 and time.perf_counter() < deadline:
