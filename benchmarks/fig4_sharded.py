@@ -8,10 +8,8 @@ exposes the collective overhead the roofline predicts.  On fake devices
 the absolute numbers measure dispatch+merge structure, not real speedup —
 the shape of the curve is the deliverable.
 
-Each kind also runs ``fused=False`` (the pre-kernel jnp locals) next to
-the fused default, plus the int8-footprint brute variant, so the
-fused-vs-unfused claim in ``benchmarks/roofline.py`` (ann-scan rows) has
-a measured counterpart in the same BENCH_fig4_sharded.json.
+The brute kind also runs its int8-footprint variant, the measured
+counterpart of the ann-scan rows in ``benchmarks/roofline.py``.
 
 Rows land in ``benchmarks/results/sharded_scaling.csv`` and on stdout via
 ``common.csv_row``.
@@ -47,12 +45,9 @@ idx_b = build_two_level(db, TwoLevelConfig(
 idx_f = build_two_level(db, TwoLevelConfig(
     n_clusters=64, top="brute", bottom="tree", kmeans_iters=4, tree_leaf=8))
 cases = (("brute", db, {}),
-         ("brute_unfused", db, {"fused": False}),
          ("brute_int8", db, {"precision": "int8"}),
          ("ivf", idx_b, {}),
-         ("ivf_unfused", idx_b, {"fused": False}),
-         ("forest", idx_f, {}),
-         ("forest_unfused", idx_f, {"fused": False}))
+         ("forest", idx_f, {}))
 for name, target, extra in cases:
     kind = name.split("_")[0]
     fn = ShardedSearchBackend(mesh, target, kind=kind, k=10,

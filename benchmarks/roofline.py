@@ -154,20 +154,18 @@ def chips_of(mesh_name: str) -> int:
 
 
 # ---------------------------------------------------------------------------
-# analytic ann-scan roofline: fused vs unfused vs int8 (PR-8)
+# analytic ann-scan roofline: f32 vs int8 corpus
 # ---------------------------------------------------------------------------
 
 
 def ann_scan_rows(b: int = 64, n: int = 1_000_000, d: int = 128,
                   k: int = 10) -> list:
-    """Three-variant HBM-traffic model of the per-shard brute scan.
+    """Two-variant HBM-traffic model of the per-shard brute scan.
 
     The scan is bandwidth-bound (2*B*N*D flops over >= N*D*4 bytes is
     ~2B flops/byte at B=64 — far below the ~240 flops/byte ridge), so
     the variants differ almost purely in bytes moved:
 
-      unfused : read db (N*D*4) + write the (B, N) f32 distance matrix
-                and read it back for top_k        -> + 2*B*N*4 bytes
       fused   : read db once; the running top-k lives in the revisited
                 output block                      -> + B*k*8 bytes
       int8    : fused traffic with the corpus as per-row-scaled int8
@@ -182,7 +180,6 @@ def ann_scan_rows(b: int = 64, n: int = 1_000_000, d: int = 128,
     db_int8 = n * d * 1.0 + n * 4.0          # codes + per-row scales
     out = b * k * 8.0                        # (dists f32, ids int32)
     variants = [
-        ("unfused", db_f32, db_f32 + 2.0 * b * n * 4.0 + out),
         ("fused", db_f32, db_f32 + out),
         ("fused-int8", db_int8, db_int8 + out),
     ]

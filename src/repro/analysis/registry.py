@@ -222,12 +222,12 @@ def _sharded_forest_search() -> Plan:
 
 @register_entry_point("fused-sharded-search")
 def _fused_sharded_search() -> Plan:
-    """PR-8 paths: ``fused=True`` routes the per-shard scan+top-k
-    through the kernel dispatch (``repro.kernels.ops``) and
-    ``precision="int8"`` additionally swaps the placed corpus for
-    per-row-scaled codes.  Both callables jit at construction and must
-    survive delta windows (scatters into the quantized corpus included)
-    without a single new compile."""
+    """The kernel-dispatched scans: every per-shard scan+top-k runs
+    through ``repro.kernels.ops``, here the int8-footprint brute scan
+    (the placed corpus as per-row-scaled codes) and the IVF probe chain.
+    Both callables jit at construction and must survive delta windows
+    (scatters into the quantized corpus included) without a single new
+    compile."""
     import numpy as np
 
     from repro.core.delta import DeltaLog
@@ -237,11 +237,11 @@ def _fused_sharded_search() -> Plan:
     db = _corpus(rng, 64)
     be8 = ShardedSearchBackend(
         _mesh1(), db, kind="brute", k=5, axes=("data",), headroom=2.0,
-        fused=True, precision="int8")
+        precision="int8")
     _, idx = _index(rng, "brute")          # bucketed flat bottom -> IVF
     bei = ShardedSearchBackend(
         _mesh1(), idx, k=5, axes=("data",), nprobe_local=_K,
-        headroom=2.0, fused=True)
+        headroom=2.0)
     q = _corpus(rng, 4)
     log = DeltaLog(base_version=0, base_n=64)
     state = {"db": db, "version": 0}

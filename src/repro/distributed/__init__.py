@@ -1,10 +1,12 @@
 """Distributed subsystem: sharding plans + mesh-sharded ANN search.
 
-``sharding``   — ShardPlan role->axis resolution and the sharded
-                 brute/IVF/forest search (corpus over one mesh axis set,
-                 queries optionally over another).
-``backend``    — pre-placed search callables that plug into
-                 ``serve.engine.ServingEngine`` as ``search_fn``.
+``sharding``   — ShardPlan role->axis resolution and the builders of
+                 the sharded brute/lexical/hybrid/IVF/forest scans
+                 (corpus over one mesh axis set, queries optionally
+                 over another).
+``backend``    — places a corpus or index once and serves the builders'
+                 jitted callables; plugs into ``serve.cell.ServingCell``
+                 as ``search_fn``.
 
 All collectives are built with ``jax.shard_map``.
 """
@@ -20,16 +22,12 @@ from repro.distributed.sharding import (
     make_sharded_forest_fn,
     make_sharded_ivf_fn,
     shard_forest,
-    sharded_brute_search,
-    sharded_forest_search,
-    sharded_ivf_search,
     slice_forest_delta,
     slice_ivf_delta,
 )
 
 __all__ = [
     "ShardPlan", "SINGLE_POD_PLAN", "MULTI_POD_PLAN", "LOCAL_PLAN",
-    "sharded_brute_search", "sharded_ivf_search", "sharded_forest_search",
     "make_sharded_brute_fn", "make_sharded_ivf_fn", "make_sharded_forest_fn",
     "shard_forest", "forest_shard_shapes", "ForestShardShapes",
     "slice_forest_delta", "slice_ivf_delta",

@@ -1,11 +1,10 @@
 """Sharded search as a ``ServingEngine`` backend.
 
-The host entries in :mod:`repro.distributed.sharding` re-place the corpus
-on every call — fine for tests, wrong for serving.  The backend does the
-expensive work once at construction (pad, shard, ``device_put``, build and
-``jit`` the shard_map callable) and leaves only query placement + the
-collective on the per-batch hot path, so the engine's micro-batches hit a
-handful of cached jit shapes.
+The one placement path for the builders in :mod:`repro.distributed.
+sharding`.  The backend does the expensive work once at construction
+(pad, shard, ``device_put``, build and ``jit`` the shard_map callable) and
+leaves only query placement + the collective on the per-batch hot path,
+so the engine's micro-batches hit a handful of cached jit shapes.
 
     eng = ServingEngine.sharded(mesh, index, k=10)        # convenience
     eng = ServingEngine(ShardedSearchBackend(mesh, db))   # explicit
@@ -119,7 +118,7 @@ class ShardedSearchBackend:
                  headroom: float = 1.0, alive=None,
                  delta_updates: bool = True,
                  delta_max_fraction: float = 0.5,
-                 fused: bool = True, precision: str = "f32",
+                 precision: str = "f32",
                  metadata=None, lexical=None):
         self.mesh = mesh
         self.k = k
@@ -191,7 +190,7 @@ class ShardedSearchBackend:
             self._rows = -(-int(np.ceil(n * headroom)) // self.n_dev)
             self._fn = jax.jit(make_sharded_brute_fn(
                 mesh, self.axes, k, self._rows, self.query_axes,
-                fused=fused, precision=precision))
+                precision=precision))
         elif kind == "ivf":
             self._K, width = (int(s) for s in target.bucket_ids.shape)
             self._cap = _bucket_width(width, headroom)
@@ -203,7 +202,7 @@ class ShardedSearchBackend:
             self._Kp = Kp
             self._fn = jax.jit(make_sharded_ivf_fn(
                 mesh, self.axes, k, nprobe_local, Kp // self.n_dev,
-                self._K, self.query_axes, fused=fused))
+                self._K, self.query_axes))
         elif kind == "forest":
             self._shapes = forest_shard_shapes(
                 target, self.n_dev, headroom,
@@ -211,7 +210,7 @@ class ShardedSearchBackend:
             self._fn = jax.jit(make_sharded_forest_fn(
                 mesh, self.axes, k, nprobe_local, beam_width,
                 self._shapes.leaf_sz, self._shapes.max_depth,
-                self.query_axes, fused=fused))
+                self.query_axes))
         else:
             raise ValueError(f"unknown backend kind {kind!r}")
         if self.lexical_src is None:
@@ -222,11 +221,9 @@ class ShardedSearchBackend:
                     "lexical slabs (lexical / hybrid modes) require "
                     "kind='brute', precision='f32'")
             self._fn_lex = jax.jit(make_sharded_lexical_fn(
-                mesh, self.axes, k, self._rows, self.query_axes,
-                fused=fused))
+                mesh, self.axes, k, self._rows, self.query_axes))
             self._fn_hyb = jax.jit(make_sharded_hybrid_fn(
-                mesh, self.axes, k, self._rows, self.query_axes,
-                fused=fused))
+                mesh, self.axes, k, self._rows, self.query_axes))
         self._place(target, alive=alive)
 
     # -- registry-backed compatibility counters ------------------------
@@ -259,7 +256,7 @@ class ShardedSearchBackend:
         put = lambda x, spec: jax.device_put(
             x, NamedSharding(self.mesh, spec))
         if self.kind == "brute" and self.precision == "int8":
-            codes, scales, valid, _, n = _brute_int8_device_arrays(
+            codes, scales, valid, n = _brute_int8_device_arrays(
                 np.asarray(target, np.float32), self.n_dev,
                 rows=self._rows, alive=alive)
             self._full_bytes = sum(int(np.asarray(a).nbytes)
@@ -272,7 +269,7 @@ class ShardedSearchBackend:
         elif self.kind == "brute":
             db_host = np.asarray(
                 getattr(target, "db", target), np.float32)
-            dbp, valid, _, n = _brute_device_arrays(
+            dbp, valid, n = _brute_device_arrays(
                 db_host, self.n_dev, rows=self._rows, alive=alive)
             self._full_bytes = int(np.asarray(dbp).nbytes
                                    + np.asarray(valid).nbytes)
@@ -287,7 +284,7 @@ class ShardedSearchBackend:
                         f"lexical slabs hold {slabs.n_docs} rows for a "
                         f"{n}-row corpus; append_docs must track "
                         "add_entities")
-                tp, fp, _, _, _ = _lexical_device_arrays(
+                tp, fp, _, _ = _lexical_device_arrays(
                     slabs.terms, slabs.tf_sat, self.n_dev,
                     rows=self._rows, alive=alive)
                 self._full_bytes += int(np.asarray(tp).nbytes

@@ -20,18 +20,23 @@ runs the paper's top+bottom search over its local shard; a tiny
 ``all_gather`` of per-chip top-k (k * 8 bytes per query) merges globally.
 The collective term is O(devices * B * k) bytes — independent of corpus
 size, which is what makes the approach scale-out friendly (EXPERIMENTS.md
-§Roofline, ann rows).  Three bottom levels are distributed here:
+§Roofline, ann rows).  Each ``make_sharded_*_fn`` builder returns the
+shard_map callable for one scan:
 
-  * ``sharded_brute_search``  — exact scan, db row-sharded;
-  * ``sharded_ivf_search``    — two-level brute bottom, buckets sharded;
-  * ``sharded_forest_search`` — two-level tree/QLBT bottom: each shard
-    holds a slice of the concatenated per-bucket forest and descends it
-    locally before the global merge.
+  * ``brute`` (f32 or int8), ``lexical`` (BM25), ``hybrid`` — exact
+    scans, corpus rows sharded;
+  * ``ivf``    — two-level brute bottom, buckets sharded;
+  * ``forest`` — two-level tree/QLBT bottom: each shard holds a slice of
+    the concatenated per-bucket forest and descends it locally.
 
-Every entry point takes ``query_axes`` to additionally shard the *query*
-batch over a second mesh axis (corpus over one, queries over the other),
-so both B and N scale; the merge all-gathers only over the corpus axes and
-results come back sharded over the query axes.
+Every per-shard scan calls its ``repro.kernels.ops`` op, which picks the
+Pallas kernel or the jnp reference by platform, and every builder ends in
+the same gather-merge (:func:`_gather_merge`).  Each builder takes
+``query_axes`` to additionally shard the *query* batch over a second mesh
+axis (corpus over one, queries over the other), so both B and N scale;
+the merge all-gathers only over the corpus axes and results come back
+sharded over the query axes.  :class:`repro.distributed.backend.
+ShardedSearchBackend` places the corpus and jits these callables once.
 
 All collectives are built with ``jax.shard_map`` so the communication
 pattern is explicit in the lowered HLO.
@@ -56,12 +61,11 @@ from jax import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.core.brute import batched_l2sq, pairwise_l2sq
+from repro.core.brute import pairwise_l2sq
 from repro.kernels import ops as kernel_ops
 
 __all__ = [
     "ShardPlan", "SINGLE_POD_PLAN", "MULTI_POD_PLAN", "LOCAL_PLAN",
-    "sharded_brute_search", "sharded_ivf_search", "sharded_forest_search",
     "make_sharded_brute_fn", "make_sharded_ivf_fn", "make_sharded_forest_fn",
     "make_sharded_lexical_fn", "make_sharded_hybrid_fn",
     "shard_forest", "forest_shard_shapes", "ForestShardShapes",
@@ -180,18 +184,16 @@ def _check_disjoint(axes, query_axes):
             "axes=('data',), query_axes=('model',)")
 
 
-def _brute_device_arrays(db, n_dev, rows=None, alive=None):
-    """Zero-pad db rows to the shard grid.  Pads (and tombstoned rows, via
-    ``alive``) are masked by an explicit per-row *valid* array rather than
-    a row count baked into the jitted program, so a mutated corpus can be
-    re-placed without re-jitting as long as the grid fits.  Returns host
-    (numpy) arrays — they go straight to their sharded placement, never
-    through the default device — as (padded db, valid mask, rows per
-    shard, real rows)."""
+def _brute_device_arrays(db, n_dev, rows, alive=None):
+    """Zero-pad db rows to the shard grid of ``rows`` a shard.  Pads (and
+    tombstoned rows, via ``alive``) are masked by an explicit per-row
+    *valid* array rather than a row count baked into the jitted program,
+    so a mutated corpus can be re-placed without re-jitting as long as the
+    grid fits.  Returns host (numpy) arrays — they go straight to their
+    sharded placement, never through the default device — as (padded db,
+    valid mask, real rows)."""
     db = np.asarray(db, np.float32)
     n = db.shape[0]
-    if rows is None:
-        rows = -(-n // n_dev)
     if rows * n_dev < n:
         raise ValueError(
             f"corpus has {n} rows but the shard grid holds only "
@@ -199,11 +201,27 @@ def _brute_device_arrays(db, n_dev, rows=None, alive=None):
     valid = np.arange(rows * n_dev) < n
     if alive is not None:
         valid[:n] &= np.asarray(alive, bool)
-    return np.pad(db, ((0, rows * n_dev - n), (0, 0))), valid, rows, n
+    return np.pad(db, ((0, rows * n_dev - n), (0, 0))), valid, n
 
 
-def _merge_gathered(gd, gi, k):
-    """(S, B, k) per-shard results -> merged (B, k)."""
+def _global_rows(li, lin, shard_rows):
+    """Shard-local row ids -> global row ids of a row-sharded corpus; the
+    (inf, -1) kernel sentinel stays -1 rather than alias shard 0's rows."""
+    return jnp.where(li >= 0, li + lin * shard_rows, -1).astype(jnp.int32)
+
+
+def _gather_merge(ld, li, axes, k):
+    """One shard's (B, k_loc) top-k -> the merged global (B, k).
+
+    A shard that holds fewer than ``k`` rows returns fewer columns: they
+    are padded with the ``(inf, -1)`` sentinel, all-gathered over the
+    corpus ``axes`` and merged by one top-k over every shard's list."""
+    pad = k - ld.shape[1]
+    if pad:
+        ld = jnp.pad(ld, ((0, 0), (0, pad)), constant_values=jnp.inf)
+        li = jnp.pad(li, ((0, 0), (0, pad)), constant_values=-1)
+    gd = jax.lax.all_gather(ld, axes, tiled=False)         # (S, B, k)
+    gi = jax.lax.all_gather(li, axes, tiled=False)
     s, b, kk = gd.shape
     cat_d = jnp.moveaxis(gd, 0, 1).reshape(b, s * kk)
     cat_i = jnp.moveaxis(gi, 0, 1).reshape(b, s * kk)
@@ -213,7 +231,7 @@ def _merge_gathered(gd, gi, k):
 
 
 def make_sharded_brute_fn(mesh, axes: tuple, k: int, shard_rows: int,
-                          query_axes: tuple = (), *, fused: bool = True,
+                          query_axes: tuple = (), *,
                           precision: str = "f32"):
     """Exact distributed search: db row-sharded over ``axes``; queries
     optionally batch-sharded over ``query_axes``.
@@ -225,80 +243,45 @@ def make_sharded_brute_fn(mesh, axes: tuple, k: int, shard_rows: int,
     count) is what lets ``ShardedSearchBackend.apply_updates`` serve
     through corpus mutations without re-jitting.
 
-    ``fused=True`` (default) routes the per-shard scan through
-    ``kernels.ops.l2_topk_op`` — on TPU the Pallas streaming kernel, which
-    never materializes the local ``(B, rows)`` distance matrix; on CPU the
-    jnp oracle whose ops are literally the unfused path's, so results are
-    bitwise-identical either way.  ``precision="int8"`` (fused only)
-    switches the operand set to per-row-scaled int8 codes — the callable
-    then takes ``(codes, scales, valid, q)``.
+    The per-shard scan is ``kernels.ops.l2_topk_op`` — on TPU the Pallas
+    streaming kernel, which never materializes the local ``(B, rows)``
+    distance matrix.  ``precision="int8"`` switches the operand set to
+    per-row-scaled int8 codes (``l2_topk_int8_op``) — the callable then
+    takes ``(codes, scales, valid, q)`` instead of ``(db, valid, q)``.
     """
     _check_disjoint(axes, query_axes)
     if precision not in ("f32", "int8"):
         raise ValueError(f"precision must be 'f32' or 'int8', "
                          f"got {precision!r}")
-    if precision == "int8" and not fused:
-        raise ValueError("precision='int8' is a fused-kernel feature; "
-                         "pass fused=True")
     k_loc = min(k, shard_rows)   # a shard may hold fewer rows than k
+    int8 = precision == "int8"
 
-    def _finish_local(ld, li, lin):
-        # shard-local slot ids -> global row ids; the (inf, -1) kernel
-        # sentinel must stay -1 rather than alias shard 0's rows
-        li = jnp.where(li >= 0, li + lin * shard_rows, -1).astype(jnp.int32)
-        if k_loc < k:
-            ld = jnp.pad(ld, ((0, 0), (0, k - k_loc)),
-                         constant_values=jnp.inf)
-            li = jnp.pad(li, ((0, 0), (0, k - k_loc)), constant_values=-1)
-        gd = jax.lax.all_gather(ld, axes, tiled=False)     # (S, B, k)
-        gi = jax.lax.all_gather(li, axes, tiled=False)
-        return _merge_gathered(gd, gi, k)
-
-    def local(db_shard, valid_shard, q):
+    def local(*operands):
+        *corpus, valid_shard, q = operands
+        scan = kernel_ops.l2_topk_int8_op if int8 else kernel_ops.l2_topk_op
         lin = jax.lax.axis_index(axes)                     # flattened index
-        if fused:
-            ld, li = kernel_ops.l2_topk_op(q, db_shard, k_loc,
-                                           valid=valid_shard)
-        else:
-            d2 = pairwise_l2sq(q, db_shard)                # (B, rows)
-            d2 = jnp.where(valid_shard[None, :], d2, jnp.inf)
-            neg, li = jax.lax.top_k(-d2, k_loc)
-            ld = -neg
-        return _finish_local(ld, li, lin)
-
-    def local_int8(codes_shard, scales_shard, valid_shard, q):
-        lin = jax.lax.axis_index(axes)
-        ld, li = kernel_ops.l2_topk_int8_op(
-            q, codes_shard, scales_shard, k_loc, valid=valid_shard)
-        return _finish_local(ld, li, lin)
+        ld, li = scan(q, *corpus, k_loc, valid=valid_shard)
+        return _gather_merge(ld, _global_rows(li, lin, shard_rows), axes, k)
 
     qs = _q_spec(query_axes)
-    if precision == "int8":
-        return shard_map(
-            local_int8, mesh=mesh,
-            in_specs=(P(tuple(axes), None), P(tuple(axes)),
-                      P(tuple(axes)), qs),
-            out_specs=(qs, qs),
-            check_vma=False,
-        )
+    corpus_specs = ((P(tuple(axes), None), P(tuple(axes))) if int8
+                    else (P(tuple(axes), None),))
     return shard_map(
         local, mesh=mesh,
-        in_specs=(P(tuple(axes), None), P(tuple(axes)), qs),
+        in_specs=(*corpus_specs, P(tuple(axes)), qs),
         out_specs=(qs, qs),
         check_vma=False,   # merge all-gathers over the corpus axes only
     )
 
 
-def _brute_int8_device_arrays(db, n_dev, rows=None, alive=None):
+def _brute_int8_device_arrays(db, n_dev, rows, alive=None):
     """int8 counterpart of ``_brute_device_arrays``: per-row symmetric
     quantization (``kernels.ops.quantize_rows_int8``) before padding, so
     pad rows are zero codes with scale 1.0 (dequantize to exact zero) and
     are masked by ``valid`` like every other dead row.  Returns
-    (codes, scales, valid, rows per shard, real rows)."""
+    (codes, scales, valid, real rows)."""
     db = np.asarray(db, np.float32)
     n = db.shape[0]
-    if rows is None:
-        rows = -(-n // n_dev)
     if rows * n_dev < n:
         raise ValueError(
             f"corpus has {n} rows but the shard grid holds only "
@@ -310,7 +293,7 @@ def _brute_int8_device_arrays(db, n_dev, rows=None, alive=None):
     valid = np.arange(rows * n_dev) < n
     if alive is not None:
         valid[:n] &= np.asarray(alive, bool)
-    return codes, scales, valid, rows, n
+    return codes, scales, valid, n
 
 
 def _pad_queries(mesh, queries, query_axes):
@@ -340,48 +323,14 @@ def _pad_term_queries(mesh, q_terms, q_weights, query_axes):
     return qt, qw, B
 
 
-def sharded_brute_search(mesh, db, queries, k=10, axes=("data", "model"),
-                         query_axes=(), fused=True, precision="f32"):
-    """Host entry: shards db rows over ``axes`` and runs the distributed
-    scan; ``query_axes`` shards the batch dim over a *disjoint* axis set.
-    ``fused``/``precision`` select the kernel path (see
-    :func:`make_sharded_brute_fn`)."""
-    n_dev = _axes_size(mesh, axes)
-    q, B = _pad_queries(mesh, queries, query_axes)
-    put = lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec))
-    if precision == "int8":
-        codes, scales, valid, rows, _ = _brute_int8_device_arrays(db, n_dev)
-        fn = make_sharded_brute_fn(mesh, tuple(axes), k, rows,
-                                   tuple(query_axes), fused=fused,
-                                   precision=precision)
-        with mesh:
-            d, i = fn(put(codes, P(tuple(axes), None)),
-                      put(scales, P(tuple(axes))),
-                      put(valid, P(tuple(axes))),
-                      put(q, _q_spec(query_axes)))
-    else:
-        dbp, valid, rows, _ = _brute_device_arrays(db, n_dev)
-        fn = make_sharded_brute_fn(mesh, tuple(axes), k, rows,
-                                   tuple(query_axes), fused=fused,
-                                   precision=precision)
-        with mesh:
-            d, i = fn(put(dbp, P(tuple(axes), None)),
-                      put(valid, P(tuple(axes))),
-                      put(q, _q_spec(query_axes)))
-    d, i = jax.device_get((d, i))
-    return np.asarray(d)[:B], np.asarray(i)[:B]
-
-
-def _lexical_device_arrays(terms, tf_sat, n_dev, rows=None, alive=None):
+def _lexical_device_arrays(terms, tf_sat, n_dev, rows, alive=None):
     """Postings-slab counterpart of ``_brute_device_arrays``: term rows
     padded with -1 (no term id 0 aliasing), tf rows with zeros; pads and
     tombstones are masked by the same explicit ``valid`` operand.
-    Returns (padded terms, padded tf_sat, valid, rows per shard, n)."""
+    Returns (padded terms, padded tf_sat, valid, real rows)."""
     t = np.asarray(terms, np.int32)
     f = np.asarray(tf_sat, np.float32)
     n = t.shape[0]
-    if rows is None:
-        rows = -(-n // n_dev)
     if rows * n_dev < n:
         raise ValueError(
             f"postings have {n} rows but the shard grid holds only "
@@ -392,43 +341,25 @@ def _lexical_device_arrays(terms, tf_sat, n_dev, rows=None, alive=None):
     valid = np.arange(rows * n_dev) < n
     if alive is not None:
         valid[:n] &= np.asarray(alive, bool)
-    return tp, fp, valid, rows, n
+    return tp, fp, valid, n
 
 
 def make_sharded_lexical_fn(mesh, axes: tuple, k: int, shard_rows: int,
-                            query_axes: tuple = (), *, fused: bool = True):
+                            query_axes: tuple = ()):
     """Distributed BM25 lexical scan: postings slabs row-sharded over
     ``axes`` — the brute layout with term/tf slabs in place of vectors.
     The callable takes ``(terms, tf_sat, valid, q_terms, q_weights)``;
     filters and tombstones compose through ``valid`` exactly as in the
     brute scan, so a filtered call reuses the unfiltered signature.
     """
-    from repro.kernels.ref import bm25_dists_ref
-
     _check_disjoint(axes, query_axes)
     k_loc = min(k, shard_rows)
 
-    def _finish_local(ld, li, lin):
-        li = jnp.where(li >= 0, li + lin * shard_rows, -1).astype(jnp.int32)
-        if k_loc < k:
-            ld = jnp.pad(ld, ((0, 0), (0, k - k_loc)),
-                         constant_values=jnp.inf)
-            li = jnp.pad(li, ((0, 0), (0, k - k_loc)), constant_values=-1)
-        gd = jax.lax.all_gather(ld, axes, tiled=False)
-        gi = jax.lax.all_gather(li, axes, tiled=False)
-        return _merge_gathered(gd, gi, k)
-
     def local(terms_shard, tf_shard, valid_shard, qt, qw):
         lin = jax.lax.axis_index(axes)
-        if fused:
-            ld, li = kernel_ops.bm25_topk_op(
-                qt, qw, terms_shard, tf_shard, k_loc, valid=valid_shard)
-        else:
-            dist = bm25_dists_ref(qt, qw, terms_shard, tf_shard)
-            dist = jnp.where(valid_shard[None, :], dist, jnp.inf)
-            neg, li = jax.lax.top_k(-dist, k_loc)
-            ld = -neg
-        return _finish_local(ld, li, lin)
+        ld, li = kernel_ops.bm25_topk_op(
+            qt, qw, terms_shard, tf_shard, k_loc, valid=valid_shard)
+        return _gather_merge(ld, _global_rows(li, lin, shard_rows), axes, k)
 
     qs = _q_spec(query_axes)
     return shard_map(
@@ -441,7 +372,7 @@ def make_sharded_lexical_fn(mesh, axes: tuple, k: int, shard_rows: int,
 
 
 def make_sharded_hybrid_fn(mesh, axes: tuple, k: int, shard_rows: int,
-                           query_axes: tuple = (), *, fused: bool = True):
+                           query_axes: tuple = ()):
     """Distributed hybrid scan: semantic L2 and BM25 fused per shard as
     ``alpha * l2sq - (1 - alpha) * bm25``.
 
@@ -450,37 +381,16 @@ def make_sharded_hybrid_fn(mesh, axes: tuple, k: int, shard_rows: int,
     — sweeping the blend mints no new executables (the recompile gate's
     ``filtered-sharded-search`` entry covers this).
     """
-    from repro.kernels.ref import bm25_dists_ref
-
     _check_disjoint(axes, query_axes)
     k_loc = min(k, shard_rows)
-
-    def _finish_local(ld, li, lin):
-        li = jnp.where(li >= 0, li + lin * shard_rows, -1).astype(jnp.int32)
-        if k_loc < k:
-            ld = jnp.pad(ld, ((0, 0), (0, k - k_loc)),
-                         constant_values=jnp.inf)
-            li = jnp.pad(li, ((0, 0), (0, k - k_loc)), constant_values=-1)
-        gd = jax.lax.all_gather(ld, axes, tiled=False)
-        gi = jax.lax.all_gather(li, axes, tiled=False)
-        return _merge_gathered(gd, gi, k)
 
     def local(db_shard, terms_shard, tf_shard, valid_shard,
               q, qt, qw, alpha):
         lin = jax.lax.axis_index(axes)
-        if fused:
-            ld, li = kernel_ops.hybrid_topk_op(
-                q, db_shard, qt, qw, terms_shard, tf_shard, alpha, k_loc,
-                valid=valid_shard)
-        else:
-            d2 = pairwise_l2sq(q, db_shard)
-            score = -bm25_dists_ref(qt, qw, terms_shard, tf_shard)
-            a = jnp.asarray(alpha, jnp.float32).reshape(1, 1)
-            dist = a * d2 - (1.0 - a) * score
-            dist = jnp.where(valid_shard[None, :], dist, jnp.inf)
-            neg, li = jax.lax.top_k(-dist, k_loc)
-            ld = -neg
-        return _finish_local(ld, li, lin)
+        ld, li = kernel_ops.hybrid_topk_op(
+            q, db_shard, qt, qw, terms_shard, tf_shard, alpha, k_loc,
+            valid=valid_shard)
+        return _gather_merge(ld, _global_rows(li, lin, shard_rows), axes, k)
 
     qs = _q_spec(query_axes)
     return shard_map(
@@ -495,7 +405,7 @@ def make_sharded_hybrid_fn(mesh, axes: tuple, k: int, shard_rows: int,
 
 def make_sharded_ivf_fn(mesh, axes: tuple, k: int, nprobe_local: int,
                         buckets_per_shard: int, n_buckets: int,
-                        query_axes: tuple = (), *, fused: bool = True):
+                        query_axes: tuple = ()):
     """Distributed two-level, brute bottom: centroids + padded buckets
     sharded over the mesh.
 
@@ -524,27 +434,17 @@ def make_sharded_ivf_fn(mesh, axes: tuple, k: int, nprobe_local: int,
             bsel = probe[:, j]                             # (B,)
             ids = bucket_ids[bsel]                         # (B, cap)
             vecs = bucket_vecs[bsel]                       # (B, cap, d)
-            if fused:
-                # distance + merge in one op (Pallas candidate kernel on
-                # TPU; the same-ops jnp oracle on CPU) — the probe chain
-                # carries the running best through the kernel
-                return kernel_ops.candidate_topk_op(
-                    q, vecs, ids, k, best_d=best_d, best_i=best_i), None
-            d2 = batched_l2sq(vecs, q)
-            d2 = jnp.where(ids >= 0, d2, jnp.inf)
-            cat_d = jnp.concatenate([best_d, d2], axis=1)
-            cat_i = jnp.concatenate([best_i, ids], axis=1)
-            neg, sel = jax.lax.top_k(-cat_d, k)
-            return (-neg, jnp.take_along_axis(cat_i, sel, 1)), None
+            # distance + merge in one op — the probe chain carries the
+            # running best through the kernel
+            return kernel_ops.candidate_topk_op(
+                q, vecs, ids, k, best_d=best_d, best_i=best_i), None
 
         B = q.shape[0]
         init = (jnp.full((B, k), jnp.inf, jnp.float32),
                 jnp.full((B, k), -1, jnp.int32))
         (ld, li), _ = jax.lax.scan(scan_probe, init,
                                    jnp.arange(nprobe_local, dtype=jnp.int32))
-        gd = jax.lax.all_gather(ld, axes, tiled=False)
-        gi = jax.lax.all_gather(li, axes, tiled=False)
-        return _merge_gathered(gd, gi, k)
+        return _gather_merge(ld, li, axes, k)
 
     qs = _q_spec(query_axes)
     return shard_map(
@@ -566,18 +466,15 @@ def _bucket_width(width: int, headroom: float = 1.0, dtype=np.float32) -> int:
     return -(-int(np.ceil(width * headroom)) // sublane) * sublane
 
 
-def _ivf_device_arrays(index, n_dev, cap=None):
+def _ivf_device_arrays(index, n_dev, cap):
     """Pad a built TwoLevelIndex's centroid/bucket tables to the shard grid
     (zero vectors, -1 ids — pads are masked by index, never by inf).
-    ``cap`` pads the bucket width beyond the index's own (update headroom:
-    a mutated index re-places into the same shapes, so the jitted search
-    is reused); by default the index's width rounded up to the sublane
-    tile (:func:`_bucket_width`).  The bucket gather runs on the host: the
-    tensor is several times the corpus (deep-10m: ~10 GB) and goes
-    straight to its shards."""
+    ``cap`` is the reserved bucket width (:func:`_bucket_width`: the
+    sublane tile, plus update headroom — a mutated index re-places into
+    the same shapes, so the jitted search is reused).  The bucket gather
+    runs on the host: the tensor is several times the corpus (deep-10m:
+    ~10 GB) and goes straight to its shards."""
     K, cap_now = index.bucket_ids.shape
-    if cap is None:
-        cap = _bucket_width(cap_now)
     if cap < cap_now:
         raise ValueError(
             f"bucket cap grew to {cap_now} > reserved {cap}; rebuild the "
@@ -591,29 +488,6 @@ def _ivf_device_arrays(index, n_dev, cap=None):
     bvecs = np.asarray(index.db, np.float32)[np.maximum(bids, 0)]
     bvecs[bids < 0] = 0.0
     return cents, bids, bvecs, Kp
-
-
-def sharded_ivf_search(mesh, index, queries, k=10, nprobe_local=2,
-                       axes=("data", "model"), query_axes=(), fused=True):
-    """Host entry: shards a built TwoLevelIndex (brute bottom) over the
-    mesh.  ``index.bucket_ids`` keeps *global* entity ids, so the merged
-    result ids are directly comparable with the single-chip index."""
-    n_dev = _axes_size(mesh, axes)
-    K = index.bucket_ids.shape[0]
-    cents, bids, bvecs, Kp = _ivf_device_arrays(index, n_dev)
-    fn = make_sharded_ivf_fn(mesh, tuple(axes), k, nprobe_local,
-                             Kp // n_dev, K, tuple(query_axes), fused=fused)
-    q, B = _pad_queries(mesh, queries, query_axes)
-    with mesh:
-        put = lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec))
-        d, i = fn(
-            put(cents, P(tuple(axes), None)),
-            put(bids, P(tuple(axes), None)),
-            put(bvecs, P(tuple(axes), None, None)),
-            put(q, _q_spec(query_axes)),
-        )
-    d, i = jax.device_get((d, i))
-    return np.asarray(d)[:B], np.asarray(i)[:B]
 
 
 # ---------------------------------------------------------------------------
@@ -633,7 +507,8 @@ class ForestShardShapes:
     Two layouts share this record:
 
     * **packed** (``node_slab == 0``): each shard's buckets are packed
-      back-to-back, minimal padding — the host entry points' layout.
+      back-to-back, minimal padding — the layout of a backend built
+      with ``delta_updates=False``.
     * **slab** (``node_slab > 0``): every bucket owns a fixed
       ``node_slab``-row node window and ``leaf_slab``-row leaf window at
       ``slot * slab``, so one bucket's rebuilt tree overwrites only its
@@ -1072,7 +947,7 @@ def slice_ivf_delta(index, cap: int, dirty_buckets) -> dict:
 
 def make_sharded_forest_fn(mesh, axes: tuple, k: int, nprobe_local: int,
                            beam_width: int, leaf_size: int, max_depth: int,
-                           query_axes: tuple = (), *, fused: bool = True):
+                           query_axes: tuple = ()):
     """Distributed two-level, tree/QLBT bottom.
 
     Per chip: score local centroids -> descend the local forest for the
@@ -1116,30 +991,13 @@ def make_sharded_forest_fn(mesh, axes: tuple, k: int, nprobe_local: int,
         cand = jnp.where(
             (cand >= 0) & (flat_bids[jnp.maximum(cand, 0)] >= 0), cand, -1)
         vecs = vecs_flat[jnp.maximum(cand, 0)]
-        if fused:
-            # rerank distance + top-k in one op (internal clamp/pad to k);
-            # slot ids map back to global entity ids afterwards
-            ld, slot = kernel_ops.candidate_topk_op(q, vecs, cand, k)
-            gids = bids.reshape(-1)[jnp.maximum(slot, 0)]
-            li = jnp.where((slot >= 0) & ~jnp.isinf(ld), gids,
-                           -1).astype(jnp.int32)
-        else:
-            d2 = batched_l2sq(vecs, q)
-            d2 = jnp.where(cand >= 0, d2, jnp.inf)
-            k_eff = min(k, cand.shape[1])
-            neg, sel = jax.lax.top_k(-d2, k_eff)
-            slot = jnp.take_along_axis(cand, sel, axis=1)
-            gids = bids.reshape(-1)[jnp.maximum(slot, 0)]
-            gids = jnp.where((slot >= 0) & ~jnp.isinf(-neg), gids, -1)
-            ld, li = -neg, gids.astype(jnp.int32)
-            if k_eff < k:
-                ld = jnp.pad(ld, ((0, 0), (0, k - k_eff)),
-                             constant_values=jnp.inf)
-                li = jnp.pad(li, ((0, 0), (0, k - k_eff)),
-                             constant_values=-1)
-        gd = jax.lax.all_gather(ld, axes, tiled=False)
-        gi = jax.lax.all_gather(li, axes, tiled=False)
-        return _merge_gathered(gd, gi, k)
+        # rerank distance + top-k in one op (internal clamp/pad to k);
+        # slot ids map back to global entity ids afterwards
+        ld, slot = kernel_ops.candidate_topk_op(q, vecs, cand, k)
+        gids = bids.reshape(-1)[jnp.maximum(slot, 0)]
+        li = jnp.where((slot >= 0) & ~jnp.isinf(ld), gids,
+                       -1).astype(jnp.int32)
+        return _gather_merge(ld, li, axes, k)
 
     qs = _q_spec(query_axes)
     corpus = lambda ndim: P(tuple(axes), *([None] * (ndim - 1)))
@@ -1153,7 +1011,7 @@ def make_sharded_forest_fn(mesh, axes: tuple, k: int, nprobe_local: int,
     )
 
 
-def _forest_device_arrays(mesh, index, axes, n_dev, shapes=None):
+def _forest_device_arrays(mesh, index, axes, n_dev, shapes):
     sh = shard_forest(index, n_dev, shapes=shapes)
     max_depth = sh.pop("max_depth")
     put = lambda x: jax.device_put(
@@ -1161,25 +1019,3 @@ def _forest_device_arrays(mesh, index, axes, n_dev, shapes=None):
         NamedSharding(mesh, P(tuple(axes), *([None] * (np.ndim(x) - 1)))),
     )
     return {name: put(v) for name, v in sh.items()}, max_depth
-
-
-def sharded_forest_search(mesh, index, queries, k=10, nprobe_local=2,
-                          beam_width=8, axes=("data", "model"),
-                          query_axes=(), fused=True):
-    """Host entry: shards a built TwoLevelIndex with a tree/QLBT forest
-    bottom level over the mesh and runs the distributed descent."""
-    n_dev = _axes_size(mesh, axes)
-    dev, max_depth = _forest_device_arrays(mesh, index, axes, n_dev)
-    fn = make_sharded_forest_fn(
-        mesh, tuple(axes), k, nprobe_local, beam_width,
-        index.config.tree_leaf, max_depth, tuple(query_axes), fused=fused,
-    )
-    q, B = _pad_queries(mesh, queries, query_axes)
-    with mesh:
-        qs = jax.device_put(q, NamedSharding(mesh, _q_spec(query_axes)))
-        d, i = fn(dev["cents"], dev["valid"], dev["roots"],
-                  dev["bucket_ids"], dev["bvecs"],
-                  dev["proj"], dev["dims"], dev["tau"], dev["children"],
-                  dev["leaf_row"], dev["leaf_entities"], qs)
-    d, i = jax.device_get((d, i))
-    return np.asarray(d)[:B], np.asarray(i)[:B]

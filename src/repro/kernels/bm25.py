@@ -51,8 +51,8 @@ def _lexical_tile(qt, qw, terms, tf_sat):
 
     Static loop over the T query slots: slot t contributes
     ``idf_t * tf_sat[d, s]`` wherever ``terms[d, s] == q_term[b, t]``.
-    The (t, then s) reduction order is shared with ``ref.bm25_dists_ref``
-    so fused and unfused scores agree bitwise on CPU.
+    The (t, then s) reduction order is the one ``ref.bm25_dists_ref``
+    uses.
     """
     score = jnp.zeros((qt.shape[0], terms.shape[0]), jnp.float32)
     for t in range(qt.shape[1]):

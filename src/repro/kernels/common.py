@@ -28,7 +28,7 @@ def merge_topk(best_d, best_i, tile_d, tile_i, k: int):
     lower-index-first rule on an id-ordered scan.  A plain per-round
     ``argmin`` would instead prefer whichever tied candidate entered the
     running list in an earlier tile — an order that depends on the ``bn``
-    tiling — so the lexicographic rule is what makes fused-vs-reference
+    tiling — so the lexicographic rule is what makes kernel-vs-reference
     conformance bitwise rather than merely set-equal.
 
     The K rounds run as a loop over the concatenated scores carried in

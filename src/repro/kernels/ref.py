@@ -8,8 +8,9 @@ Shared contract (matches the kernels): ``k`` is clamped to the candidate
 count internally; slots with no live candidate come back as the
 ``(inf, -1)`` sentinel — callers treat id ``-1`` as "no candidate".  The
 distance expansions are *exactly* the ones in ``core.brute``
-(``pairwise_l2sq`` / ``batched_l2sq``), which is what keeps the fused
-sharded path bitwise-identical to the unfused jnp path on CPU.
+(``pairwise_l2sq`` / ``batched_l2sq``), so off the chip the sharded scans
+compute what ``core.brute`` computes; the conformance suites hold each
+Pallas kernel body (interpret mode) to these oracles.
 """
 from __future__ import annotations
 
@@ -64,9 +65,9 @@ def candidate_topk_ref(queries, vecs, ids, k: int = 10,
     """Oracle for `bucket_topk`: per-query candidate tiles, optional
     carried running best (the IVF probe-chain pattern).
 
-    Uses ``batched_l2sq`` + ``lax.top_k`` — the literal ops of the
-    unfused sharded IVF/forest locals — so the CPU dispatch of the fused
-    path cannot drift from the unfused path by construction.
+    Uses ``batched_l2sq`` + ``lax.top_k``, the same expansion as
+    ``core.brute``; the IVF probe chain through the kernel body matches
+    it bitwise (``tests/test_conformance.py``).
     """
     q = queries.astype(jnp.float32)
     v = vecs.astype(jnp.float32)
@@ -110,8 +111,8 @@ def hamming_topk_ref(qcodes, codes, k: int = 10, *, valid=None):
 
 def bm25_dists_ref(q_terms, q_weights, terms, tf_sat):
     """(B, N) BM25 ranking distances (``-score``), reduced in the same
-    (term-slot, then doc-slot) order as the fused kernel's static loop —
-    the order match is what keeps fused vs unfused bitwise on CPU."""
+    (term-slot, then doc-slot) order as the kernel's static loop, so the
+    kernel body and this oracle sum the same terms in the same order."""
     qt = q_terms.astype(jnp.int32)
     qw = q_weights.astype(jnp.float32)
     t = terms.astype(jnp.int32)

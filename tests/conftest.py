@@ -1,3 +1,5 @@
+import contextlib
+import functools
 import os
 import sys
 
@@ -24,3 +26,23 @@ def subprocess_env():
         "HOME": os.environ.get("HOME", "/root"),
         "JAX_PLATFORMS": "cpu",
     }
+
+
+_KERNEL_OPS = ("l2_topk_op", "l2_topk_int8_op", "candidate_topk_op",
+               "bm25_topk_op", "hybrid_topk_op")
+
+
+@contextlib.contextmanager
+def pallas_kernels(monkeypatch):
+    """Inside the block the sharded scans' ``repro.kernels.ops``
+    dispatchers run the Pallas kernel body (interpret mode off the chip)
+    instead of the jnp reference.  A jitted search traces its dispatch at
+    its first call, so call a backend's search inside the block to put
+    the kernel body in its program."""
+    from repro.kernels import ops
+
+    with monkeypatch.context() as m:
+        for name in _KERNEL_OPS:
+            m.setattr(ops, name,
+                      functools.partial(getattr(ops, name), force_pallas=True))
+        yield

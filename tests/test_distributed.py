@@ -1,11 +1,13 @@
 """Multi-device semantics on 8 fake devices (subprocess: tests themselves
-run single-device).  Covers: distributed exact/IVF/forest search, query+
-corpus 2-axis sharding, the serving backend, compressed psum, elastic
-checkpoint resharding, and a sharded LM train step.
+run single-device).  Covers: distributed exact/IVF/forest search through
+``ShardedSearchBackend``, query+corpus 2-axis sharding, the shared
+gather-merge of the row-sharded scans, the serving engine, compressed
+psum, elastic checkpoint resharding, and a sharded LM train step.
 
 The subprocess tests are marked ``slow`` (each pays a fresh 8-device JAX
 start-up); the in-process slicing tests run in the default CI job.
 """
+import json
 import subprocess
 import sys
 import textwrap
@@ -45,24 +47,14 @@ def _run(body: str):
 def test_query_axes_must_be_disjoint_from_corpus_axes():
     """A shared axis would top-k-merge results of *different* queries —
     refuse loudly instead of returning silently wrong neighbors."""
-    from repro.distributed import sharded_brute_search
+    from repro.distributed import ShardedSearchBackend
     from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((1,), ("data",))
     db = np.zeros((8, 4), np.float32)
     with pytest.raises(ValueError, match="disjoint"):
-        sharded_brute_search(mesh, db, db[:2], 2,
-                             axes=("data",), query_axes=("data",))
-
-
-def test_core_distributed_shim_reexports():
-    """Old import path keeps working after the move to repro.distributed."""
-    from repro.core import distributed as old
-    from repro.distributed import sharding as new
-
-    assert old.sharded_brute_search is new.sharded_brute_search
-    assert old.sharded_ivf_search is new.sharded_ivf_search
-    assert old.sharded_forest_search is new.sharded_forest_search
+        ShardedSearchBackend(mesh, db, k=2, axes=("data",),
+                             query_axes=("data",))
 
 
 def test_shard_forest_slices_conserve_entities():
@@ -339,13 +331,13 @@ def test_ivf_backend_reserves_tile_aligned_width():
 @slow
 def test_sharded_brute_matches_exact():
     out = _run("""
-    from repro.distributed import sharded_brute_search
+    from repro.distributed import ShardedSearchBackend
     from repro.core.brute import brute_search
     mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(0)
     db = rng.normal(size=(3000, 16)).astype(np.float32)
     q = rng.normal(size=(32, 16)).astype(np.float32)
-    d, i = sharded_brute_search(mesh, db, q, 10)
+    d, i = ShardedSearchBackend(mesh, db, k=10)(q)
     dt, it = brute_search(q, db, 10)
     print("MATCH", float((np.asarray(i) == it).mean()))
     """)
@@ -358,14 +350,14 @@ def test_query_and_corpus_2axis_sharded_matches_exact():
     results identical to the single-device scan (B not divisible by the
     query axis exercises the host-side batch pad)."""
     out = _run("""
-    from repro.distributed import sharded_brute_search
+    from repro.distributed import ShardedSearchBackend
     from repro.core.brute import brute_search
     mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(1)
     db = rng.normal(size=(2500, 16)).astype(np.float32)
     q = rng.normal(size=(37, 16)).astype(np.float32)   # 37 % 4 != 0
-    d, i = sharded_brute_search(mesh, db, q, 10,
-                                axes=("data",), query_axes=("model",))
+    d, i = ShardedSearchBackend(mesh, db, k=10, axes=("data",),
+                                query_axes=("model",))(q)
     dt, it = brute_search(q, db, 10)
     print("MATCH", float((np.asarray(i) == it).mean()),
           float(np.abs(np.asarray(d) - dt).max()))
@@ -373,10 +365,71 @@ def test_query_and_corpus_2axis_sharded_matches_exact():
     assert "MATCH 1.0" in out
 
 
+_SCANS = ("brute-f32", "brute-int8", "lexical", "hybrid")
+
+
+@pytest.fixture(scope="module")
+def gather_merge_rows():
+    """One 8-device run of every row-sharded builder with ``k`` above
+    both a shard's rows and the corpus: 13 rows over 8 shards (2 a
+    shard), row 5 tombstoned, ``k`` 16.  Returns each scan's
+    (dists, ids) and the exact f32 order of the dense corpus."""
+    out = _run("""
+    from repro.core.lexical import build_lexical_slabs, query_operands
+    from repro.distributed import ShardedSearchBackend
+    mesh = make_mesh((2, 4), ("data", "model"))
+    rng = np.random.default_rng(11)
+    db = rng.normal(size=(13, 16)).astype(np.float32)
+    q = rng.normal(size=(3, 16)).astype(np.float32)
+    slabs = build_lexical_slabs(
+        [list(rng.integers(0, 20, 6)) for _ in range(13)], 20)
+    qt, qw = query_operands(
+        [list(rng.integers(0, 20, 4)) for _ in range(3)], slabs)
+    alive = np.ones(13, bool)
+    alive[5] = False
+    kw = dict(k=16, alive=alive)
+    be = ShardedSearchBackend(mesh, db, lexical=slabs, **kw)
+    be8 = ShardedSearchBackend(mesh, db, precision="int8", **kw)
+    res = {"brute-f32": be(q), "brute-int8": be8(q),
+           "lexical": be(q, mode="lexical", q_terms=qt, q_weights=qw),
+           "hybrid": be(q, mode="hybrid", alpha=0.5,
+                        q_terms=qt, q_weights=qw)}
+    d2 = ((q[:, None, :] - db[None, :, :]) ** 2).sum(-1)
+    d2[:, 5] = np.inf
+    res["exact"] = (np.argsort(d2, axis=1, kind="stable")[:, :12], None)
+    import json
+    print("RESULT", json.dumps({name: [np.asarray(a).tolist()
+                                       for a in r if a is not None]
+                                for name, r in res.items()}))
+    """)
+    res = json.loads(out.split("RESULT", 1)[1])
+    return {name: tuple(np.asarray(a) for a in r)
+            for name, r in res.items()}
+
+
+@slow
+@pytest.mark.parametrize("scan", _SCANS)
+def test_gather_merge_pads_k_above_shard_rows(gather_merge_rows, scan):
+    """``k`` above a shard's rows: each shard returns its 2 rows, the
+    shared gather-merge pads them to ``k`` with ``(inf, -1)`` and maps
+    shard-local slots to global rows.  Every live row comes back exactly
+    once, in ascending distance, and the tail is the sentinel."""
+    d, i = gather_merge_rows[scan]
+    assert d.shape == i.shape == (3, 16)
+    live = [r for r in range(13) if r != 5]
+    for b in range(3):
+        assert sorted(i[b, :12].tolist()) == live, (scan, i[b])
+        assert np.isfinite(d[b, :12]).all()
+        assert (np.diff(d[b, :12]) >= 0).all()
+        assert (i[b, 12:] == -1).all() and np.isinf(d[b, 12:]).all()
+    if scan == "brute-f32":
+        assert np.array_equal(i[:, :12], gather_merge_rows["exact"][0])
+
+
 @slow
 def test_sharded_ivf_recall():
     out = _run("""
-    from repro.distributed import sharded_ivf_search
+    from repro.distributed import ShardedSearchBackend
     from repro.core.two_level import TwoLevelConfig, build_two_level
     from repro.core.brute import brute_search
     from repro.core.metrics import recall_at_k
@@ -387,7 +440,7 @@ def test_sharded_ivf_recall():
     q = db[:64] + rng.normal(size=(64, 16)).astype(np.float32) * 0.05
     idx = build_two_level(db, TwoLevelConfig(n_clusters=64, top="brute",
                           bottom="brute", kmeans_iters=5))
-    d, i = sharded_ivf_search(mesh, idx, q, 10, nprobe_local=4)
+    d, i = ShardedSearchBackend(mesh, idx, k=10, nprobe_local=4)(q)
     _, it = brute_search(q, db, 10)
     print("RECALL", recall_at_k(np.asarray(i), it))
     """)
@@ -396,11 +449,14 @@ def test_sharded_ivf_recall():
 
 
 @slow
-def test_sharded_forest_recall():
+@pytest.mark.parametrize("delta_updates", [True, False],
+                         ids=["slab", "packed"])
+def test_sharded_forest_recall(delta_updates):
     """Tree/QLBT forest bottom level, sharded: each chip descends its own
-    slice of the concatenated forest; merged recall clears the paper bar."""
-    out = _run("""
-    from repro.distributed import sharded_forest_search
+    slice of the concatenated forest; merged recall clears the paper bar
+    in both forest layouts (``delta_updates`` picks slab or packed)."""
+    out = _run(f"    delta_updates = {delta_updates}\n" + """
+    from repro.distributed import ShardedSearchBackend
     from repro.core.two_level import TwoLevelConfig, build_two_level
     from repro.core.brute import brute_search
     from repro.core.metrics import recall_at_k
@@ -411,13 +467,15 @@ def test_sharded_forest_recall():
     q = db[:64] + rng.normal(size=(64, 16)).astype(np.float32) * 0.05
     idx = build_two_level(db, TwoLevelConfig(n_clusters=64, top="brute",
                           bottom="tree", kmeans_iters=5, tree_leaf=8))
-    d, i = sharded_forest_search(mesh, idx, q, 10, nprobe_local=4,
-                                 beam_width=8)
+    d, i = ShardedSearchBackend(mesh, idx, k=10, nprobe_local=4,
+                                beam_width=8,
+                                delta_updates=delta_updates)(q)
     _, it = brute_search(q, db, 10)
     print("RECALL", recall_at_k(np.asarray(i), it))
-    d2, i2 = sharded_forest_search(mesh, idx, q, 10, nprobe_local=4,
-                                   beam_width=8, axes=("data",),
-                                   query_axes=("model",))
+    d2, i2 = ShardedSearchBackend(mesh, idx, k=10, nprobe_local=4,
+                                  beam_width=8, axes=("data",),
+                                  query_axes=("model",),
+                                  delta_updates=delta_updates)(q)
     print("RECALL2", recall_at_k(np.asarray(i2), it))
     """)
     assert float(out.split("RECALL2")[1].strip()) > 0.8
@@ -431,7 +489,7 @@ def test_sharded_ivf_full_probe_identical_to_single_device():
     the unsharded index — including bucket-grid padding (K % shards != 0)
     and row padding (N % shards != 0), the PR 2 edge cases."""
     out = _run("""
-    from repro.distributed import sharded_ivf_search
+    from repro.distributed import ShardedSearchBackend
     from repro.core.two_level import TwoLevelConfig, build_two_level
     mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(3)
@@ -441,7 +499,7 @@ def test_sharded_ivf_full_probe_identical_to_single_device():
     idx = build_two_level(db, TwoLevelConfig(n_clusters=50, top="brute",
                           bottom="brute", kmeans_iters=5))
     Kp = -(-50 // 8) * 8
-    d, i = sharded_ivf_search(mesh, idx, q, 10, nprobe_local=Kp // 8)
+    d, i = ShardedSearchBackend(mesh, idx, k=10, nprobe_local=Kp // 8)(q)
     ds, js, _ = idx.search(q, 10, nprobe=50)
     ok_d = np.allclose(np.sort(d), np.sort(ds), rtol=1e-4, atol=1e-4)
     ok_i = all(set(i[b].tolist()) == set(js[b].tolist()) for b in range(40))
@@ -451,12 +509,15 @@ def test_sharded_ivf_full_probe_identical_to_single_device():
 
 
 @slow
-def test_sharded_forest_full_probe_identical_to_single_device():
+@pytest.mark.parametrize("delta_updates", [True, False],
+                         ids=["slab", "packed"])
+def test_sharded_forest_full_probe_identical_to_single_device(delta_updates):
     """Every shard descends the same per-bucket trees the single-device
     forest holds; with every bucket probed on both sides the candidate
-    sets coincide, so the merged (id, distance) sets must be identical."""
-    out = _run("""
-    from repro.distributed import sharded_forest_search
+    sets coincide, so the merged (id, distance) sets must be identical —
+    in both forest layouts, with bucket-grid padding (K % shards != 0)."""
+    out = _run(f"    delta_updates = {delta_updates}\n" + """
+    from repro.distributed import ShardedSearchBackend
     from repro.core.two_level import TwoLevelConfig, build_two_level
     mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(4)
@@ -466,8 +527,9 @@ def test_sharded_forest_full_probe_identical_to_single_device():
     idx = build_two_level(db, TwoLevelConfig(n_clusters=50, top="brute",
                           bottom="tree", kmeans_iters=5, tree_leaf=8))
     Kp = -(-50 // 8) * 8
-    d, i = sharded_forest_search(mesh, idx, q, 10, nprobe_local=Kp // 8,
-                                 beam_width=8)
+    d, i = ShardedSearchBackend(mesh, idx, k=10, nprobe_local=Kp // 8,
+                                beam_width=8,
+                                delta_updates=delta_updates)(q)
     ds, js, _ = idx.search(q, 10, nprobe=50, beam_width=8)
     ok_d = np.allclose(np.sort(d), np.sort(ds), rtol=1e-4, atol=1e-4)
     ok_i = all(set(i[b].tolist()) == set(js[b].tolist()) for b in range(40))

@@ -3,16 +3,18 @@
 The filter surface (``repro.core.metadata``) compiles predicates to row
 masks that are *data, not shapes* — so the contract is strong:
 
-  (a) fused and unfused backends are bitwise-identical under every
-      filter, at selectivities {0, 0.05, 0.5, 1.0}, on the fresh index
-      AND after a localized mutation shipped down the delta path, for
-      every top x bottom combo;
+  (a) a backend traced through the Pallas kernel body agrees with the
+      same backend through the reference dispatch under every filter,
+      at selectivities {0, 0.05, 0.5, 1.0}, on the fresh index AND after
+      a localized mutation shipped down the delta path, for every top x
+      bottom combo: identical ids, bitwise distances for the IVF kinds
+      and distances within 1e-5 for the forest rerank;
   (b) no returned id ever violates the predicate (or a tombstone);
   (c) selectivity 0 yields the full ``(inf, -1)`` sentinel surface with
       no NaNs; a selectivity-1.0 predicate is bitwise-equal to the
       unfiltered call;
-  (d) the brute kind is additionally *exact*: bitwise-equal to a pure
-      numpy masked-scan oracle, fresh and post-delta;
+  (d) the brute kind is additionally *exact*: through either kernel,
+      the ids of a pure numpy masked-scan oracle, fresh and post-delta;
   (e) lexical (BM25 slab) and hybrid modes match their numpy oracles
       and compose with filters, without minting jit signatures beyond
       the three per-mode callables;
@@ -35,6 +37,8 @@ from repro.core.two_level import (
 )
 from repro.distributed.backend import ShardedSearchBackend
 from repro.launch.mesh import make_mesh
+
+from conftest import pallas_kernels
 
 N, D, K, CAP, NQ, TOPK = 600, 8, 16, 96, 16, 10
 COMBOS = [(t, b) for t in TOP_ALGOS for b in BOTTOM_ALGOS]
@@ -83,7 +87,7 @@ def _oracle(q, db, ok, k):
 
 
 @pytest.mark.parametrize("top,bottom", COMBOS)
-def test_filtered_fused_vs_unfused(top, bottom):
+def test_filtered_kernel_body_vs_reference(top, bottom, monkeypatch):
     rng = np.random.default_rng(700 + TOP_ALGOS.index(top) * 10
                                 + BOTTOM_ALGOS.index(bottom))
     db = _corpus(rng, N)
@@ -93,19 +97,26 @@ def test_filtered_fused_vs_unfused(top, bottom):
     mesh = make_mesh((1,), ("data",))
     kw = dict(k=TOPK, axes=("data",), nprobe_local=K, beam_width=8,
               headroom=1.5)
-    be_f = ShardedSearchBackend(mesh, idx, fused=True, **kw)
-    be_u = ShardedSearchBackend(mesh, idx, fused=False, **kw)
+    be_r = ShardedSearchBackend(mesh, idx, **kw)
+    be_p = ShardedSearchBackend(mesh, idx, **kw)
     q = _corpus(rng, NQ)
 
     def check(tag):
         alive = (np.ones(meta.n_rows, bool) if idx.alive is None
                  else np.asarray(idx.alive, bool))
         for name, fs in SPECS:
-            df, i_f = be_f(q, filter_spec=fs)
-            du, iu = be_u(q, filter_spec=fs)
-            assert np.array_equal(df, du) and np.array_equal(i_f, iu), (
-                f"{top}/{bottom} [{tag} {name}]: fused filtered scan "
-                f"diverged from unfused")
+            df, i_f = be_r(q, filter_spec=fs)
+            with pallas_kernels(monkeypatch):
+                dp, ip = be_p(q, filter_spec=fs)
+            assert np.array_equal(ip, i_f), (
+                f"{top}/{bottom} [{tag} {name}]: kernel body filtered "
+                f"ids diverged from the reference")
+            if be_r.kind == "forest":
+                np.testing.assert_allclose(dp, df, rtol=1e-5, atol=1e-5)
+            else:
+                assert np.array_equal(dp, df), (
+                    f"{top}/{bottom} [{tag} {name}]: kernel body filtered "
+                    f"distances diverged from the reference")
             ok = fs.mask(meta, alive.shape[0]) & alive
             real = i_f[i_f >= 0]
             assert ok[real].all(), (
@@ -118,8 +129,8 @@ def test_filtered_fused_vs_unfused(top, bottom):
                 assert not np.isnan(df).any()
         # selectivity 1.0 (a real predicate admitting every row) must be
         # bitwise-equal to the unfiltered call
-        d0, i0 = be_f(q)
-        d1, i1 = be_f(q, filter_spec=SPECS[-1][1])
+        d0, i0 = be_r(q)
+        d1, i1 = be_r(q, filter_spec=SPECS[-1][1])
         assert np.array_equal(d0, d1) and np.array_equal(i0, i1), (
             f"{top}/{bottom} [{tag}]: selectivity-1.0 filter changed "
             f"the unfiltered answer")
@@ -135,13 +146,13 @@ def test_filtered_fused_vs_unfused(top, bottom):
            + 0.1 * rng.normal(size=(5, D))).astype(np.float32)
     idx.add_entities(new, metadata={"pct": np.full(5, 2, np.int32)})
     man = idx.pop_delta()
-    stf = be_f.apply_updates(idx, delta=man)
-    stu = be_u.apply_updates(idx, delta=man)
-    assert stf["mode"] == stu["mode"] == "delta", (stf, stu)
+    stf = be_r.apply_updates(idx, delta=man)
+    stp = be_p.apply_updates(idx, delta=man)
+    assert stf["mode"] == stp["mode"] == "delta", (stf, stp)
     check("post-delta")
     # tombstoned rows stay dead under every filter
     for _, fs in SPECS:
-        _, i_f = be_f(q, filter_spec=fs)
+        _, i_f = be_r(q, filter_spec=fs)
         assert not np.isin(i_f, dele).any(), (
             f"{top}/{bottom}: deleted id returned through a filtered "
             f"delta-path search")
@@ -152,14 +163,23 @@ def test_filtered_fused_vs_unfused(top, bottom):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("fused", [True, False])
-def test_filtered_brute_exact_oracle(fused):
-    rng = np.random.default_rng(800 + int(fused))
+@pytest.mark.parametrize("kernel", ["reference", "pallas"])
+def test_filtered_brute_exact_oracle(kernel, monkeypatch):
+    if kernel == "pallas":
+        # the whole test traces the scan through the kernel body
+        with pallas_kernels(monkeypatch):
+            _filtered_brute_exact_oracle(801)
+    else:
+        _filtered_brute_exact_oracle(800)
+
+
+def _filtered_brute_exact_oracle(seed):
+    rng = np.random.default_rng(seed)
     db = _corpus(rng, N)
     meta = _meta_for(rng, N)
     mesh = make_mesh((1,), ("data",))
     be = ShardedSearchBackend(
-        mesh, db, k=TOPK, axes=("data",), headroom=1.5, fused=fused,
+        mesh, db, k=TOPK, axes=("data",), headroom=1.5,
         metadata=meta, delta_max_fraction=1.0)
     q = _corpus(rng, NQ)
     compound = (FilterSpec.range("pct", 10, 80)
@@ -218,8 +238,7 @@ def test_lexical_and_hybrid_conformance():
     mesh = make_mesh((1,), ("data",))
     kw = dict(k=TOPK, axes=("data",), headroom=1.5, metadata=meta,
               lexical=slabs, delta_max_fraction=1.0)
-    be_f = ShardedSearchBackend(mesh, db, fused=True, **kw)
-    be_u = ShardedSearchBackend(mesh, db, fused=False, **kw)
+    be_r = ShardedSearchBackend(mesh, db, **kw)
     alive = np.ones(n, bool)
     fs = FilterSpec.range("pct", 0, 49)
     emask = fs.mask(meta, n)
@@ -240,40 +259,34 @@ def test_lexical_and_hybrid_conformance():
         order = np.argsort(comb, axis=1, kind="stable")[:, :TOPK]
         return np.take_along_axis(comb, order, 1)
 
-    # lexical: fused == unfused bitwise; distances match the BM25 oracle
-    dl, il = be_f(q, mode="lexical", q_terms=qt, q_weights=qw)
-    du, iu = be_u(q, mode="lexical", q_terms=qt, q_weights=qw)
-    assert np.array_equal(dl, du) and np.array_equal(il, iu)
+    # lexical: distances match the BM25 oracle
+    dl, il = be_r(q, mode="lexical", q_terms=qt, q_weights=qw)
     assert np.allclose(dl, lex_oracle(alive), atol=1e-5)
 
-    # hybrid across alphas: fused == unfused bitwise, oracle-close;
-    # alpha is an operand, so no alpha mints a new jit signature
+    # hybrid across alphas: oracle-close; alpha is an operand, so no
+    # alpha mints a new jit signature
     for alpha in (0.0, 0.3, 1.0):
-        dh, ih = be_f(q, mode="hybrid", alpha=alpha,
+        dh, ih = be_r(q, mode="hybrid", alpha=alpha,
                       q_terms=qt, q_weights=qw)
-        dhu, ihu = be_u(q, mode="hybrid", alpha=alpha,
-                        q_terms=qt, q_weights=qw)
-        assert np.array_equal(dh, dhu) and np.array_equal(ih, ihu), (
-            f"hybrid alpha={alpha}: fused diverged from unfused")
         assert np.allclose(dh, hyb_oracle(alive, alpha), atol=1e-4), (
             f"hybrid alpha={alpha} diverged from the numpy oracle")
 
     # filters compose with both modes (predicate mask ANDed into valid)
     for mode in ("lexical", "hybrid"):
-        d, i = be_f(q, mode=mode, filter_spec=fs,
+        d, i = be_r(q, mode=mode, filter_spec=fs,
                     q_terms=qt, q_weights=qw)
         real = i[i >= 0]
         assert emask[real].all(), (
             f"{mode}+filter returned an excluded id")
-        d0, i0 = be_f(q, mode=mode, filter_spec=FilterSpec.eq("pct", 777),
+        d0, i0 = be_r(q, mode=mode, filter_spec=FilterSpec.eq("pct", 777),
                       q_terms=qt, q_weights=qw)
         assert np.all(i0 == -1) and np.all(np.isinf(d0))
         assert not np.isnan(d0).any()
 
     # exactly one jitted callable per mode, regardless of how many
     # filter/alpha combinations were dispatched above
-    _ = be_f(q, filter_spec=fs)          # semantic mode, filtered
-    assert be_f.jit_cache_size() == 3, be_f.jit_cache_size()
+    _ = be_r(q, filter_spec=fs)          # semantic mode, filtered
+    assert be_r.jit_cache_size() == 3, be_r.jit_cache_size()
 
     # delta path: appended docs join the lexical scan, under a filter
     # that admits them, and the slab scatter is delta-shaped
@@ -282,9 +295,9 @@ def test_lexical_and_hybrid_conformance():
     slabs.append_docs([list(rng.integers(0, nv, 6)) for _ in range(8)])
     meta.append_rows({"pct": np.full(8, 2, np.int32)}, 8)
     man = DeltaManifest(base_version=0, version=1, base_n=n, n=n + 8)
-    st = be_f.apply_updates(db2, delta=man)
+    st = be_r.apply_updates(db2, delta=man)
     assert st["mode"] == "delta", st
-    d, i = be_f(q, mode="lexical", filter_spec=fs,
+    d, i = be_r(q, mode="lexical", filter_spec=fs,
                 q_terms=qt, q_weights=qw)
     emask2 = fs.mask(meta, n + 8)
     real = i[i >= 0]
@@ -295,7 +308,7 @@ def test_lexical_and_hybrid_conformance():
     order = np.argsort(bdm, axis=1, kind="stable")[:, :TOPK]
     assert np.allclose(d, np.take_along_axis(bdm, order, 1), atol=1e-5), (
         "post-delta filtered lexical scan diverged from the oracle")
-    assert be_f.jit_cache_size() == 3, "delta apply minted a signature"
+    assert be_r.jit_cache_size() == 3, "delta apply minted a signature"
 
 
 def test_mode_and_filter_validation():
